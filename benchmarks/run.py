@@ -44,6 +44,11 @@ def main():
     ap.add_argument('--fast', action='store_true',
                     help='shorter horizons for CI')
     args = ap.parse_args()
+    # every benchmark here is a CPU run (benchmarks/README.md); shard_scale
+    # needs virtual devices, which must exist before jax initializes
+    os.environ.setdefault('JAX_PLATFORMS', 'cpu')
+    os.environ['XLA_FLAGS'] = (os.environ.get('XLA_FLAGS', '') +
+                               ' --xla_force_host_platform_device_count=8')
 
     failures = []
     for name, desc in BENCHES:

@@ -76,14 +76,14 @@ def _streaming_frontend(n_streams: int = 72, max_new: int = 6,
     import asyncio
 
     from repro.core.clock import RealClock
-    from repro.launch.serve import build_node
+    from repro.launch.serve import demo_node
     from repro.serving.frontend.app import FrontendApp
     from repro.serving.frontend.driver import AsyncNodeDriver
     from repro.serving.frontend.loadgen import (
         LoadGenerator, TraceEntry, make_online_trace)
     from repro.serving.frontend.testing import ASGIClient
 
-    node = build_node(clock=RealClock())
+    node = demo_node(clock=RealClock())
     # all arrivals in the first 10% of the horizon → peak concurrency is
     # the whole trace (streams outlive the arrival window)
     trace = make_online_trace(n_streams, horizon_s=horizon_s,

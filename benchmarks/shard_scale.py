@@ -27,6 +27,14 @@ Two trajectories in one file (``BENCH_shard.json``):
      compute than the truncation it replaces;
    - at least one victim is actually rescued (≥1 cross-pool migration).
 
+The caller provides the virtual devices; the module sets no environment:
+
+    JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
+        PYTHONPATH=src python benchmarks/shard_scale.py --smoke
+
+(``scripts/ci.sh`` and ``benchmarks/run.py`` do this.)  Mesh sizes the
+process has no devices for are skipped.
+
 Writes ``results/shard_scale.json`` and mirrors ``BENCH_shard.json`` at
 the repo root.  ``--smoke`` runs mesh sizes {1, 2} with a short window
 plus the full (cheap) rescue comparison.
@@ -37,13 +45,6 @@ import json
 import os
 import time
 from typing import Dict, List, Optional
-
-# must land before the first jax import (see tests/conftest.py)
-_FLAG = '--xla_force_host_platform_device_count=8'
-if 'xla_force_host_platform_device_count' not in os.environ.get('XLA_FLAGS', ''):
-    os.environ['XLA_FLAGS'] = \
-        f"{os.environ.get('XLA_FLAGS', '')} {_FLAG}".strip()
-os.environ.setdefault('JAX_PLATFORMS', 'cpu')
 
 import numpy as np
 

@@ -2,10 +2,9 @@
 # CI gate: tier-1 suite + a fast kernel-parity subset.
 #
 # The kernel-parity subset re-runs first and verbosely even though tier-1
-# includes it: the Pallas kernels are where jax API drift lands (compiler
-# params, shard_map, cost_analysis — all shimmed in
-# src/repro/kernels/common.py), so a jax bump that breaks them fails loudly
-# at the top of the log instead of somewhere inside the full run.
+# includes it: the Pallas kernels are where jax API drift lands, so a jax
+# bump that breaks them fails loudly at the top of the log instead of
+# somewhere inside the full run.
 #
 # Usage:  scripts/ci.sh [--kernels-only|--regen-api]
 set -euo pipefail
@@ -146,7 +145,8 @@ echo "== kernel hot-path smoke (fused decode regression gate) =="
 python benchmarks/kernel_hotpath.py --smoke
 
 echo "== shard-scale smoke (mesh parity + zero-recompute rescue gate) =="
-python benchmarks/shard_scale.py --smoke
+XLA_FLAGS=--xla_force_host_platform_device_count=8 \
+    python benchmarks/shard_scale.py --smoke
 
 echo "== disagg smoke (2-pool handoff: bit-identity + zero-recompute gate) =="
 python benchmarks/disagg.py --smoke

@@ -6,9 +6,8 @@ no-op, so the same model code runs on a single CPU device, under pjit with a
 (data, model) mesh, or inside a partial-auto shard_map.
 
 Manual-collective code (e.g. the compressed gradient all-reduce) enters
-shard_map through :func:`manual_shard_map` here rather than ``jax.shard_map``
-directly — the underlying API moved between jax versions, and the
-compat shim in :mod:`repro.kernels.common` owns that surface.
+shard_map through :func:`manual_shard_map`, which fixes its replication
+check off.
 """
 from __future__ import annotations
 
@@ -18,8 +17,6 @@ from typing import Dict, Optional, Sequence, Tuple, Union
 
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-from repro.kernels.common import shard_map as _shard_map
 
 AxisVal = Union[None, str, Tuple[str, ...]]
 
@@ -162,14 +159,14 @@ def logical_to_spec(axes: Sequence[Optional[str]],
 
 def manual_shard_map(fn, mesh: Mesh, in_specs, out_specs, *,
                      check_replication: bool = False):
-    """Version-portable ``shard_map`` entry for manual-collective code.
+    """``jax.shard_map`` for manual-collective code.
 
-    ``check_replication=False`` matches the historical ``check_rep=False`` /
-    ``check_vma=False`` default our collectives rely on (psum of int8
-    payloads is replication-breaking by design).
+    ``check_replication=False`` (``check_vma``) is the default our
+    collectives rely on: psum of int8 payloads is replication-breaking by
+    design.
     """
-    return _shard_map(fn, mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_replication=check_replication)
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_replication)
 
 
 def constrain(x, axes: Sequence[Optional[str]]):

@@ -7,13 +7,9 @@
 #                                                    paged_attention_decode)
 #     from repro.kernels.rwkv6.ops import wkv6
 #
-# This __init__ re-exports ONLY the compat/toolkit shims: the ops modules are
-# deliberately not imported here — non-kernel consumers of
-# repro.kernels.common (e.g. distributed/sharding.py, on every model import
-# path) must not pay the Pallas ops import cost, and the function names
-# shadow their subpackage names, so package-level function re-exports are an
-# import-order hazard.  Shared machinery and ALL version-sensitive JAX
-# surface (compiler params, shard_map, interpret fallback) live in
-# repro.kernels.common.
-from repro.kernels.common import (  # noqa: F401
-    compiler_params, cost_analysis_dict, resolve_interpret, shard_map)
+# The ops modules are deliberately not imported here: non-kernel consumers
+# of repro.kernels.common (e.g. the models, on every import path) must not
+# pay the Pallas ops import cost, and the function names shadow their
+# subpackage names, so package-level function re-exports are an
+# import-order hazard.  Shared machinery lives in repro.kernels.common.
+from repro.kernels.common import resolve_interpret  # noqa: F401

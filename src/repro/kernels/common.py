@@ -1,18 +1,6 @@
-"""Shared kernel toolkit + JAX version-compat shim.
+"""Shared kernel toolkit.
 
-Every version-sensitive JAX surface the kernels touch goes through this
-module, so an API rename in a jax upgrade is a one-file fix instead of a
-sweep over every ``kernel.py``:
-
-- **compiler params**: ``pltpu.CompilerParams`` (jax ≥ 0.5) vs
-  ``pltpu.TPUCompilerParams`` (jax 0.4.x) — :func:`compiler_params`;
-- **shard_map**: ``jax.shard_map(..., check_vma=)`` (jax ≥ 0.6) vs
-  ``jax.experimental.shard_map.shard_map(..., check_rep=)`` —
-  :func:`shard_map`;
-- **cost analysis**: ``Compiled.cost_analysis()`` returns a dict on new jax
-  and a one-element list of dicts on 0.4.x — :func:`cost_analysis_dict`.
-
-It also centralizes the machinery all three Pallas kernels (flash, paged,
+It centralizes the machinery all three Pallas kernels (flash, paged,
 wkv6) previously re-implemented:
 
 - TPU-lane-aligned block/tile-size selection and padding
@@ -27,19 +15,13 @@ wkv6) previously re-implemented:
 """
 from __future__ import annotations
 
-import functools
-import inspect
-import re
-from typing import Optional, Sequence, Tuple
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.pallas import tpu as pltpu
 
 __all__ = [
     'NEG_INF', 'LANES', 'SUBLANES',
-    'jax_version', 'jax_at_least',
-    'compiler_params', 'shard_map', 'cost_analysis_dict',
     'resolve_interpret',
     'ceil_div', 'round_up', 'pick_block', 'pad_axis_to',
     'online_softmax_init', 'online_softmax_update', 'online_softmax_finalize',
@@ -55,74 +37,6 @@ NEG_INF = -1e30
 # is 8 (doubles for bf16 / quadruples for int8 — see the Pallas guide).
 LANES = 128
 SUBLANES = 8
-
-
-# ---------------------------------------------------------------------------
-# Version detection
-# ---------------------------------------------------------------------------
-
-@functools.lru_cache(maxsize=None)
-def jax_version() -> Tuple[int, ...]:
-    """``jax.__version__`` as an int tuple ('0.4.37' → (0, 4, 37))."""
-    return tuple(int(p) for p in
-                 re.findall(r'\d+', jax.__version__)[:3])
-
-
-def jax_at_least(*version: int) -> bool:
-    return jax_version() >= tuple(version)
-
-
-# ---------------------------------------------------------------------------
-# Compat shims
-# ---------------------------------------------------------------------------
-
-# jax 0.5 renamed TPUCompilerParams → CompilerParams (and kept a deprecation
-# alias for a while); 0.4.x only has the TPU-prefixed name.
-_COMPILER_PARAMS_CLS = getattr(pltpu, 'CompilerParams', None) \
-    or getattr(pltpu, 'TPUCompilerParams')
-
-
-def compiler_params(*, dimension_semantics: Optional[Sequence[str]] = None,
-                    **kwargs):
-    """Construct Mosaic compiler params under either jax naming.
-
-    Kernels must use this instead of touching ``pltpu.*CompilerParams``
-    directly (enforced by the kernel parity suite staying green across jax
-    upgrades).
-    """
-    return _COMPILER_PARAMS_CLS(dimension_semantics=dimension_semantics,
-                                **kwargs)
-
-
-def shard_map(f, mesh, *, in_specs, out_specs, check_replication: bool = True):
-    """Version-portable ``shard_map``.
-
-    Two independent API moves are absorbed here: the promotion from
-    ``jax.experimental.shard_map.shard_map`` to ``jax.shard_map``, and the
-    ``check_rep`` → ``check_vma`` kwarg rename — they landed in different
-    jax releases, so the kwarg is probed from the actual signature rather
-    than inferred from where the function lives.
-    """
-    if hasattr(jax, 'shard_map'):
-        fn = jax.shard_map
-    else:
-        from jax.experimental.shard_map import shard_map as fn
-    params = inspect.signature(fn).parameters
-    check_kw = 'check_vma' if 'check_vma' in params else 'check_rep'
-    return fn(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-              **{check_kw: check_replication})
-
-
-def cost_analysis_dict(compiled) -> dict:
-    """``Compiled.cost_analysis()`` normalized to a flat dict.
-
-    jax 0.4.x returns a one-element list of per-program dicts; newer jax
-    returns the dict directly (and may return None for trivial programs).
-    """
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return dict(cost or {})
 
 
 def resolve_interpret(interpret: Optional[bool]) -> bool:

@@ -13,9 +13,9 @@ MXU because every lane is masked (a fully-skipped variant would use
 
 Block sizes default to (128, 128): q/k/v tiles of 128×Dh bf16 keep the
 working set ≤ ~200 KB in VMEM at Dh=128 and align to the 128-lane MXU.
-Shared machinery (online softmax, masking, padding, compiler-params
-construction) comes from :mod:`repro.kernels.common` — this file contains
-only the flash-specific grid/BlockSpec layout.
+Shared machinery (online softmax, masking, padding) comes from
+:mod:`repro.kernels.common` — this file contains only the flash-specific
+grid/BlockSpec layout.
 """
 from __future__ import annotations
 
@@ -105,7 +105,7 @@ def flash_attention_bhsd(q, k, v, *, causal: bool = True,
             pltpu.VMEM((block_q,), jnp.float32),        # l
             pltpu.VMEM((block_q, d), jnp.float32),      # acc
         ],
-        compiler_params=kc.compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=('parallel', 'parallel', 'arbitrary')),
         interpret=interpret,
     )(q, k, v)

@@ -5,18 +5,21 @@ table* — the indirection Valve's quarantine remap rewrites.  The page table
 and per-request lengths ride in scalar-prefetch SMEM
 (PrefetchScalarGridSpec), and the K/V BlockSpec index maps dereference
 ``page_table[b, ip]`` to pick the physical page, so the gather never
-materializes in HBM: pages stream HBM→VMEM one (page_size × Dh) tile at a
-time while the online-softmax state sits in VMEM scratch.
+materializes in HBM: pages stream HBM→VMEM one whole (page_size × Hkv × Dh)
+page at a time while the online-softmax state sits in VMEM scratch.
 
-Grid ``(B, Hkv, n_pages)``; pages is innermost/sequential.  Tokens past a
-request's length are masked in-kernel; a quarantined page (id 0) streams
+Grid ``(B, n_pages)``; pages is innermost/sequential.  Each step fetches
+one physical page for *every* kv head — the block's last two dims are the
+pool's full (Hkv, Dh), which is what the TPU lowering's (8, 128) tiling
+rule admits without relaying out the pool — and loops over the kv heads
+inside the kernel with per-head (G,) / (G, Dh) softmax state.  Tokens past
+a request's length are masked in-kernel; a quarantined page (id 0) streams
 garbage that is either masked (healthy request) or discarded by Valve's
 invalidation-recompute contract — never a fault, by construction.
 
-GQA: q for one (b, kv-head) is the (group, Dh) block of query heads; with
-group ≤ 8 and Dh = 128 the q tile is one MXU pass per page.  Shared
-machinery (online softmax, length masking, compiler-params construction)
-comes from :mod:`repro.kernels.common`.
+GQA: q for one (b, kv-head) is the (group, Dh) block of query heads.
+Shared machinery (online softmax, length masking) comes from
+:mod:`repro.kernels.common`.
 """
 from __future__ import annotations
 
@@ -31,32 +34,72 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.kernels import common as kc
 
 
+def _walk_page(q_ref, k_ref, v_ref, m_ref, l_ref, acc_ref, *, page_index,
+               kv_len, page_size: int, scale: float) -> None:
+    """Fold one fetched page into every kv head's running softmax state.
+    q_ref: (1, Hkv, G, D); k/v_ref: (1, pg, Hkv, D); state: (Hkv, G[, D])."""
+    for h in range(q_ref.shape[1]):
+        q = q_ref[0, h].astype(jnp.float32)               # (G, D)
+        k = k_ref[0, :, h, :].astype(jnp.float32)         # (pg, D)
+        v = v_ref[0, :, h, :].astype(jnp.float32)         # (pg, D)
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * scale
+        pos = kc.block_positions(page_index, page_size, s.shape, 1)
+        s = kc.mask_block_scores(s, k_pos=pos, kv_len=kv_len)
+        m_ref[h], l_ref[h], acc_ref[h] = kc.online_softmax_update(
+            s, v, m_ref[h], l_ref[h], acc_ref[h])
+
+
+def _flush_heads(o_ref, l_ref, acc_ref) -> None:
+    for h in range(o_ref.shape[1]):
+        o_ref[0, h] = kc.online_softmax_finalize(
+            acc_ref[h], l_ref[h]).astype(o_ref.dtype)
+
+
+def _state_scratch(hkv: int, rows: int, d: int):
+    return [pltpu.VMEM((hkv, rows), jnp.float32),
+            pltpu.VMEM((hkv, rows), jnp.float32),
+            pltpu.VMEM((hkv, rows, d), jnp.float32)]
+
+
 def _paged_kernel(page_table_ref, lengths_ref, q_ref, k_ref, v_ref, o_ref,
                   m_ref, l_ref, acc_ref, *, page_size: int, scale: float):
     b = pl.program_id(0)
-    ip = pl.program_id(2)
-    np_ = pl.num_programs(2)
+    ip = pl.program_id(1)
 
     @pl.when(ip == 0)
     def _init():
         kc.online_softmax_init(m_ref, l_ref, acc_ref)
 
-    q = q_ref[0, 0].astype(jnp.float32)               # (G, D)
-    k = k_ref[0, :, 0].astype(jnp.float32)            # (pg, D)
-    v = v_ref[0, :, 0].astype(jnp.float32)            # (pg, D)
+    _walk_page(q_ref, k_ref, v_ref, m_ref, l_ref, acc_ref, page_index=ip,
+               kv_len=lengths_ref[b], page_size=page_size, scale=scale)
 
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale
-    pos = kc.block_positions(ip, page_size, s.shape, 1)
-    s = kc.mask_block_scores(s, k_pos=pos, kv_len=lengths_ref[b])
-
-    m_ref[...], l_ref[...], acc_ref[...] = kc.online_softmax_update(
-        s, v, m_ref[...], l_ref[...], acc_ref[...])
-
-    @pl.when(ip == np_ - 1)
+    @pl.when(ip == pl.num_programs(1) - 1)
     def _flush():
-        o_ref[0, 0] = kc.online_softmax_finalize(
-            acc_ref[...], l_ref[...]).astype(o_ref.dtype)
+        _flush_heads(o_ref, l_ref, acc_ref)
+
+
+def _page_spec(pg: int, hkv: int, d: int, page_of):
+    """K/V block: one whole physical page (all kv heads) chosen by
+    ``page_of(grid indices, *scalar refs)`` — the page-table dereference."""
+    return pl.BlockSpec((1, pg, hkv, d),
+                        lambda *a: (page_of(*a), 0, 0, 0))
+
+
+def _table_page(ib, ip, page_table, *_):
+    """Physical page of request ``ib``'s ``ip``-th table entry."""
+    return page_table[ib, ip]
+
+
+def _row_spec(*shape):
+    """Request ``ib``'s block (grid axis 0) of a (B, *shape) array."""
+    return pl.BlockSpec((1,) + shape,
+                        lambda ib, *_: (ib,) + (0,) * len(shape))
+
+
+def _whole_spec(shape):
+    """The whole array as one block, at every grid step."""
+    return pl.BlockSpec(shape, lambda *_: (0,) * len(shape))
 
 
 def paged_attention_bhgd(q, pool_k, pool_v, page_table, lengths, *,
@@ -65,43 +108,27 @@ def paged_attention_bhgd(q, pool_k, pool_v, page_table, lengths, *,
     """q: (B, Hkv, G, D); pools: (P, pg, Hkv, D) — global paged layout;
     page_table: (B, maxp) physical ids (0 = quarantine); lengths: (B,)."""
     b, hkv, g, d = q.shape
-    p_total, pg, _, _ = pool_k.shape
+    pg = pool_k.shape[1]
     maxp = page_table.shape[1]
     scale = d ** -0.5 if scale is None else scale
     interpret = kc.resolve_interpret(interpret)
 
-    grid = (b, hkv, maxp)
-    kernel = functools.partial(_paged_kernel, page_size=pg, scale=scale)
-
+    page = _page_spec(pg, hkv, d, _table_page)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1, g, d),
-                         lambda ib, ih, ip, pt, ln: (ib, ih, 0, 0)),
-            # the page-table dereference: physical page for (request, step)
-            pl.BlockSpec((1, pg, 1, d),
-                         lambda ib, ih, ip, pt, ln: (pt[ib, ip], 0, ih, 0)),
-            pl.BlockSpec((1, pg, 1, d),
-                         lambda ib, ih, ip, pt, ln: (pt[ib, ip], 0, ih, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, g, d),
-                               lambda ib, ih, ip, pt, ln: (ib, ih, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((g,), jnp.float32),
-            pltpu.VMEM((g,), jnp.float32),
-            pltpu.VMEM((g, d), jnp.float32),
-        ],
+        grid=(b, maxp),
+        in_specs=[_row_spec(hkv, g, d), page, page],
+        out_specs=_row_spec(hkv, g, d),
+        scratch_shapes=_state_scratch(hkv, g, d),
     )
-    out = pl.pallas_call(
-        kernel,
+    return pl.pallas_call(
+        functools.partial(_paged_kernel, page_size=pg, scale=scale),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, g, d), q.dtype),
-        compiler_params=kc.compiler_params(
-            dimension_semantics=('parallel', 'parallel', 'arbitrary')),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=('parallel', 'arbitrary')),
         interpret=interpret,
     )(page_table, lengths, q, pool_k, pool_v)
-    return out
 
 
 # ---------------------------------------------------------------------------
@@ -118,70 +145,59 @@ def _shared_run_kernel(shared_pages_ref, share_pos_ref, q_ref, k_ref, v_ref,
                        mask_ref, m_out_ref, l_out_ref, acc_out_ref,
                        m_ref, l_ref, acc_ref, *, page_size: int,
                        scale: float):
-    js = pl.program_id(1)
-    ns = pl.num_programs(1)
+    """q_ref: (Hkv, B·G, D) — the whole batch's queries per kv head;
+    mask_ref: (1, B·G, 1) this slot's participation per query row."""
+    js = pl.program_id(0)
 
     @pl.when(js == 0)
     def _init():
         kc.online_softmax_init(m_ref, l_ref, acc_ref)
 
-    q = q_ref[:, 0].astype(jnp.float32)               # (B, G, D)
-    b, g, d = q.shape
-    k = k_ref[0, :, 0].astype(jnp.float32)            # (pg, D)
-    v = v_ref[0, :, 0].astype(jnp.float32)
-    s = jax.lax.dot_general(q.reshape(b * g, d), k,
-                            (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale
     # participation mask: rows not sharing this slot (and quarantine
     # padding slots) score NEG_INF.  A row masked at every slot so far
     # carries garbage mass at m = NEG_INF; the first finite score — here
     # or in the tail phase — rescales it away (alpha = exp(-inf) = 0), so
     # no explicit reset is needed.  Shared pages are fully filled by the
     # publication contract, so no kv_len mask applies in this phase.
-    ok = jnp.repeat(mask_ref[:, 0] > 0, g)            # (B*G,)
-    s = jnp.where(ok[:, None], s, kc.NEG_INF)
+    ok = mask_ref[0] > 0                                 # (B·G, 1)
+    for h in range(q_ref.shape[0]):
+        q = q_ref[h].astype(jnp.float32)                 # (B·G, D)
+        k = k_ref[0, :, h, :].astype(jnp.float32)        # (pg, D)
+        v = v_ref[0, :, h, :].astype(jnp.float32)
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * scale
+        s = jnp.where(ok, s, kc.NEG_INF)
+        m_ref[h], l_ref[h], acc_ref[h] = kc.online_softmax_update(
+            s, v, m_ref[h], l_ref[h], acc_ref[h])
 
-    m_ref[...], l_ref[...], acc_ref[...] = kc.online_softmax_update(
-        s, v, m_ref[...], l_ref[...], acc_ref[...])
-
-    @pl.when(js == ns - 1)
+    @pl.when(js == pl.num_programs(0) - 1)
     def _flush():
-        m_out_ref[:, 0] = m_ref[...].reshape(b, g)
-        l_out_ref[:, 0] = l_ref[...].reshape(b, g)
-        acc_out_ref[:, 0] = acc_ref[...].reshape(b, g, d)
+        m_out_ref[...] = m_ref[...]
+        l_out_ref[...] = l_ref[...]
+        acc_out_ref[...] = acc_ref[...]
 
 
 def _tail_kernel(tail_pt_ref, start_ref, lengths_ref, q_ref, k_ref, v_ref,
                  m0_ref, l0_ref, acc0_ref, o_ref, m_ref, l_ref, acc_ref, *,
                  page_size: int, scale: float):
     b = pl.program_id(0)
-    ip = pl.program_id(2)
-    np_ = pl.num_programs(2)
+    ip = pl.program_id(1)
 
     @pl.when(ip == 0)
     def _init():
         # resume the online softmax from the shared-run partial state
-        m_ref[...] = m0_ref[0, 0]
-        l_ref[...] = l0_ref[0, 0]
-        acc_ref[...] = acc0_ref[0, 0]
+        m_ref[...] = m0_ref[0]
+        l_ref[...] = l0_ref[0]
+        acc_ref[...] = acc0_ref[0]
 
-    q = q_ref[0, 0].astype(jnp.float32)               # (G, D)
-    k = k_ref[0, :, 0].astype(jnp.float32)            # (pg, D)
-    v = v_ref[0, :, 0].astype(jnp.float32)
-
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale
     # tail pages sit AFTER the row's shared run: shift by start_pages
-    pos = kc.block_positions(start_ref[b] + ip, page_size, s.shape, 1)
-    s = kc.mask_block_scores(s, k_pos=pos, kv_len=lengths_ref[b])
+    _walk_page(q_ref, k_ref, v_ref, m_ref, l_ref, acc_ref,
+               page_index=start_ref[b] + ip, kv_len=lengths_ref[b],
+               page_size=page_size, scale=scale)
 
-    m_ref[...], l_ref[...], acc_ref[...] = kc.online_softmax_update(
-        s, v, m_ref[...], l_ref[...], acc_ref[...])
-
-    @pl.when(ip == np_ - 1)
+    @pl.when(ip == pl.num_programs(1) - 1)
     def _flush():
-        o_ref[0, 0] = kc.online_softmax_finalize(
-            acc_ref[...], l_ref[...]).astype(o_ref.dtype)
+        _flush_heads(o_ref, l_ref, acc_ref)
 
 
 def paged_attention_prefix_shared_bhgd(q, pool_k, pool_v, shared_pages,
@@ -196,79 +212,61 @@ def paged_attention_prefix_shared_bhgd(q, pool_k, pool_v, shared_pages,
     pg = pool_k.shape[1]
     n_slots = shared_pages.shape[0]
     maxp = tail_pt.shape[1]
+    rows = b * g
     scale = d ** -0.5 if scale is None else scale
     interpret = kc.resolve_interpret(interpret)
 
-    # phase 1: grid (Hkv, S) — each shared physical page streams HBM→VMEM
-    # exactly once per kv-head for the WHOLE batch
+    # phase 1: grid (S,) — each shared physical page streams HBM→VMEM
+    # exactly once for the WHOLE batch and every kv head.  The batch's
+    # queries are regrouped per kv head, and the participation mask per
+    # query row, so every block's last two dims are the full array dims
+    # (tiny XLA relayouts of q and the mask, never of the pool).
+    q_rows = q.transpose(1, 0, 2, 3).reshape(hkv, rows, d)
+    row_mask = jnp.repeat(share_mask.T, g, axis=1)[:, :, None]  # (S, B·G, 1)
+    state = [jax.ShapeDtypeStruct((hkv, rows), jnp.float32),
+             jax.ShapeDtypeStruct((hkv, rows), jnp.float32),
+             jax.ShapeDtypeStruct((hkv, rows, d), jnp.float32)]
     shared_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(hkv, n_slots),
+        grid=(n_slots,),
         in_specs=[
-            pl.BlockSpec((b, 1, g, d), lambda ih, js, sp, spos: (0, ih, 0, 0)),
-            pl.BlockSpec((1, pg, 1, d),
-                         lambda ih, js, sp, spos: (sp[js], 0, ih, 0)),
-            pl.BlockSpec((1, pg, 1, d),
-                         lambda ih, js, sp, spos: (sp[js], 0, ih, 0)),
-            pl.BlockSpec((b, 1), lambda ih, js, sp, spos: (0, js)),
+            _whole_spec((hkv, rows, d)),
+            _page_spec(pg, hkv, d, lambda js, sp, spos: sp[js]),
+            _page_spec(pg, hkv, d, lambda js, sp, spos: sp[js]),
+            pl.BlockSpec((1, rows, 1), lambda js, sp, spos: (js, 0, 0)),
         ],
-        out_specs=[
-            pl.BlockSpec((b, 1, g), lambda ih, js, sp, spos: (0, ih, 0)),
-            pl.BlockSpec((b, 1, g), lambda ih, js, sp, spos: (0, ih, 0)),
-            pl.BlockSpec((b, 1, g, d), lambda ih, js, sp, spos: (0, ih, 0, 0)),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((b * g,), jnp.float32),
-            pltpu.VMEM((b * g,), jnp.float32),
-            pltpu.VMEM((b * g, d), jnp.float32),
-        ],
+        out_specs=[_whole_spec(s.shape) for s in state],
+        scratch_shapes=_state_scratch(hkv, rows, d),
     )
     m0, l0, acc0 = pl.pallas_call(
         functools.partial(_shared_run_kernel, page_size=pg, scale=scale),
         grid_spec=shared_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((b, hkv, g), jnp.float32),
-            jax.ShapeDtypeStruct((b, hkv, g), jnp.float32),
-            jax.ShapeDtypeStruct((b, hkv, g, d), jnp.float32),
-        ],
-        compiler_params=kc.compiler_params(
-            dimension_semantics=('parallel', 'arbitrary')),
+        out_shape=state,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=('arbitrary',)),
         interpret=interpret,
-    )(shared_pages, share_pos, q, pool_k, pool_v, share_mask)
+    )(shared_pages, share_pos, q_rows, pool_k, pool_v, row_mask)
+    # back to per-request blocks for the tail walk
+    m0 = m0.reshape(hkv, b, g).transpose(1, 0, 2)
+    l0 = l0.reshape(hkv, b, g).transpose(1, 0, 2)
+    acc0 = acc0.reshape(hkv, b, g, d).transpose(1, 0, 2, 3)
 
     # phase 2: the stock per-request page walk over the tails, resuming
     # from phase 1's partial (m, l, acc)
+    page = _page_spec(pg, hkv, d, _table_page)
     tail_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(b, hkv, maxp),
-        in_specs=[
-            pl.BlockSpec((1, 1, g, d),
-                         lambda ib, ih, ip, pt, st, ln: (ib, ih, 0, 0)),
-            pl.BlockSpec((1, pg, 1, d),
-                         lambda ib, ih, ip, pt, st, ln: (pt[ib, ip], 0, ih, 0)),
-            pl.BlockSpec((1, pg, 1, d),
-                         lambda ib, ih, ip, pt, st, ln: (pt[ib, ip], 0, ih, 0)),
-            pl.BlockSpec((1, 1, g),
-                         lambda ib, ih, ip, pt, st, ln: (ib, ih, 0)),
-            pl.BlockSpec((1, 1, g),
-                         lambda ib, ih, ip, pt, st, ln: (ib, ih, 0)),
-            pl.BlockSpec((1, 1, g, d),
-                         lambda ib, ih, ip, pt, st, ln: (ib, ih, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, g, d),
-                               lambda ib, ih, ip, pt, st, ln: (ib, ih, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((g,), jnp.float32),
-            pltpu.VMEM((g,), jnp.float32),
-            pltpu.VMEM((g, d), jnp.float32),
-        ],
+        grid=(b, maxp),
+        in_specs=[_row_spec(hkv, g, d), page, page, _row_spec(hkv, g),
+                  _row_spec(hkv, g), _row_spec(hkv, g, d)],
+        out_specs=_row_spec(hkv, g, d),
+        scratch_shapes=_state_scratch(hkv, g, d),
     )
-    out = pl.pallas_call(
+    return pl.pallas_call(
         functools.partial(_tail_kernel, page_size=pg, scale=scale),
         grid_spec=tail_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, g, d), q.dtype),
-        compiler_params=kc.compiler_params(
-            dimension_semantics=('parallel', 'parallel', 'arbitrary')),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=('parallel', 'arbitrary')),
         interpret=interpret,
     )(tail_pt, start_pages, lengths, q, pool_k, pool_v, m0, l0, acc0)
-    return out

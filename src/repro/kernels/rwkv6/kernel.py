@@ -10,9 +10,9 @@ and only the O(T/c) chunk boundary is sequential.
 
 Grid ``(B, H, n_chunks)`` — chunks innermost/sequential ('arbitrary');
 state scratch (K, V) f32.  Padding tokens must carry w=1, k=0, r=0 (decay
-no-op, no state contribution) — the wrapper guarantees this.  Padding and
-compiler-params construction go through :mod:`repro.kernels.common` (wkv6
-has no softmax, so the online-softmax helpers don't apply here).
+no-op, no state contribution) — the wrapper guarantees this.  Padding goes
+through :mod:`repro.kernels.common` (wkv6 has no softmax, so the
+online-softmax helpers don't apply here).
 """
 from __future__ import annotations
 
@@ -113,7 +113,7 @@ def wkv6_bthk(r, k, v, w, u, state, *, chunk: int = 64,
             jax.ShapeDtypeStruct((b, h, dk, dv), jnp.float32),
         ),
         scratch_shapes=[pltpu.VMEM((dk, dv), jnp.float32)],
-        compiler_params=kc.compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=('parallel', 'parallel', 'arbitrary')),
         interpret=interpret,
     )(r, k, v, w, u, state)

@@ -105,7 +105,7 @@ def unembed_sample_pallas(last, unembed, seed, *, temperature: float = 0.0,
                           temperature=float(temperature)),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, 1), jnp.int32),
-        compiler_params=kc.compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=('arbitrary',)),
         interpret=interpret,
     )(jnp.asarray(seed, jnp.int32), last, wp)

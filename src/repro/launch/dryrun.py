@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: lower + compile every (arch × shape × mesh) cell.
 
 For each cell this proves the sharding config is coherent (no mismatched
@@ -22,6 +19,7 @@ benchmarks/roofline.py consume these).
 """
 import argparse
 import json
+import os
 import re
 import subprocess
 import sys
@@ -99,10 +97,9 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str,
         rec.update(status='FAILED', error=f'{type(e).__name__}: {e}')
         return rec
 
-    from repro.kernels.common import cost_analysis_dict
     from repro.launch import hlo_analysis as ha
     mem = compiled.memory_analysis()
-    cost = cost_analysis_dict(compiled)
+    cost = compiled.cost_analysis() or {}
     costs = ha.analyze(compiled.as_text())
 
     n_chips = meshlib.chips(mesh)
@@ -213,6 +210,9 @@ def sweep(out_path: str, *, use_subprocess: bool, meshes=('single', 'multi'),
 
 
 def main():
+    # 512 virtual host devices for the production meshes; this must land
+    # before jax initializes its backends, which nothing above has done yet
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
     ap = argparse.ArgumentParser()
     ap.add_argument('--arch', default=None)
     ap.add_argument('--shape', default=None)

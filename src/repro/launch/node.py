@@ -160,7 +160,14 @@ class NodeOrchestrator:
         destination for cross-pool rescues."""
         model = build_model(model_cfg)
         if params is None:
-            params = model.init_params(jax.random.PRNGKey(seed))
+            # on a mesh, each weight is generated straight into its shard:
+            # a model larger than one device never lands whole on device 0
+            shardings = None
+            if engine_cfg.mesh is not None:
+                pool_pages = (pool or self.pool).n_pages
+                shardings = model.serve_shardings(engine_cfg.mesh,
+                                                  pool_pages)[0]
+            params = model.init_params(jax.random.PRNGKey(seed), shardings)
         if pool is not None and pool is not self.pool:
             eng = Engine(model, params, pool, engine_cfg, clock=self.clock)
         else:

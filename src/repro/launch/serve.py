@@ -19,6 +19,10 @@ the paper's Fig. 10 uses; benchmarks/colocation_matrix.py runs the full
 strategy grid in simulation, benchmarks/serve_throughput.py measures this
 driver.
 
+:func:`build_node` builds whatever model configs it is handed — the
+published widths by default (``--http``); the scripted ``--steps`` demo,
+the tests and CI hand it ``reduced()`` configs (:func:`demo_node`).
+
     # heterogeneous demo: online qwen3-0.6b + offline qwen3-0.6b AND
     # offline internlm2-1.8b (reduced) on one pool
     PYTHONPATH=src python -m repro.launch.serve --steps 400
@@ -26,51 +30,96 @@ driver.
     # pick the offline models explicitly (repeatable flag)
     PYTHONPATH=src python -m repro.launch.serve \\
         --offline-arch internlm2-1.8b --offline-arch qwen3-0.6b
+
+    # HTTP front-end over the published-width models (a TPU-sized job)
+    PYTHONPATH=src python -m repro.launch.serve --http --port 8080
 """
 from __future__ import annotations
 
 import argparse
 from typing import Optional, Sequence
 
+import jax
 import numpy as np
 
-from repro.configs import get_config, reduced as reduce_cfg
+from repro.configs import ModelConfig, get_config, reduced as reduce_cfg
 from repro.core.clock import RealClock
 from repro.core.runtime import RuntimeConfig, ValveRuntime
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.node import NodeOrchestrator
 from repro.serving.engine import EngineConfig
 from repro.serving.kvpool import KVPool
 
 DEFAULT_OFFLINE_ARCHS = ('qwen3-0.6b', 'internlm2-1.8b')
 
+# the reduced-width CPU demo: 2-layer d_model-64 models over a 4-token page
+DEMO_PAGE_SIZE = 4
+DEMO_SHAPE = dict(n_handles=24, pages_per_handle=8, max_seq=96,
+                  prefill_chunk=16)
 
-def build_node(*, arch: str = 'qwen3-0.6b',
-               offline_archs: Sequence[str] = DEFAULT_OFFLINE_ARCHS,
-               seed: int = 0, clock=None, page_size: int = 4,
-               max_prefill_reqs: int = 4,
-               piggyback_decode: bool = True,
+
+def build_node(online: ModelConfig, offline: Sequence[ModelConfig] = (), *,
+               n_handles: int = 32, pages_per_handle: int = 16,
+               max_seq: int = 512, prefill_chunk: int = 128,
+               max_prefill_reqs: int = 4, piggyback_decode: bool = True,
+               mesh=None, seed: int = 0, clock=None,
                idle_advance: float = 1e-3) -> NodeOrchestrator:
-    """One node: online ``arch`` + one offline engine per ``offline_archs``
-    entry (heterogeneous model configs over one pool/runtime)."""
-    pool = KVPool(n_handles=24, pages_per_handle=8, page_size=page_size,
-                  reserved_handles=2)
+    """One node: an ``online`` engine + one offline engine per ``offline``
+    config (heterogeneous models over one pool/runtime), at the widths the
+    configs carry.  Every config must share one page size — the pool's.
+    ``mesh`` (a ``jax.sharding.Mesh``) shards every engine over it and
+    gives the runtime one dispatch gate per mesh device."""
+    cfgs = [online, *offline]
+    page_size = online.page_size
+    if any(c.page_size != page_size for c in cfgs):
+        raise ValueError(f'configs disagree on page size: '
+                         f'{[(c.name, c.page_size) for c in cfgs]}')
+    pool = KVPool(n_handles=n_handles, pages_per_handle=pages_per_handle,
+                  page_size=page_size, reserved_handles=2)
     clock = clock or RealClock()
-    rt = ValveRuntime(pool, RuntimeConfig(n_devices=1, t_cool_init=0.002),
-                      clock=clock)
+    rt = ValveRuntime(pool, RuntimeConfig(n_devices=1, mesh=mesh,
+                                          t_cool_init=0.002), clock=clock)
     node = NodeOrchestrator(rt, idle_advance=idle_advance)
 
     def ecfg(klass: str) -> EngineConfig:
-        return EngineConfig(max_batch=8, max_seq=96, prefill_chunk=16,
+        return EngineConfig(max_batch=8, max_seq=max_seq,
+                            prefill_chunk=prefill_chunk,
                             max_prefill_reqs=max_prefill_reqs,
-                            piggyback_decode=piggyback_decode, klass=klass)
+                            piggyback_decode=piggyback_decode, klass=klass,
+                            mesh=mesh)
 
-    node.add_engine(reduce_cfg(get_config(arch), page_size=page_size),
-                    ecfg('online'), seed=seed, name=f'online:{arch}')
-    for i, oarch in enumerate(offline_archs):
-        node.add_engine(reduce_cfg(get_config(oarch), page_size=page_size),
-                        ecfg('offline'), seed=seed + i,
-                        name=f'offline{i}:{oarch}')
+    node.add_engine(online, ecfg('online'), seed=seed,
+                    name=f'online:{online.name}')
+    for i, cfg in enumerate(offline):
+        node.add_engine(cfg, ecfg('offline'), seed=seed + i,
+                        name=f'offline{i}:{cfg.name}')
     return node
+
+
+def demo_node(*, arch: str = 'qwen3-0.6b',
+              offline_archs: Sequence[str] = DEFAULT_OFFLINE_ARCHS,
+              **kw) -> NodeOrchestrator:
+    """:func:`build_node` over ``reduced()`` configs at the CPU demo's
+    small pool and sequence budget (``kw`` overrides either)."""
+    def small(a):
+        return reduce_cfg(get_config(a), page_size=DEMO_PAGE_SIZE)
+    return build_node(small(arch), [small(a) for a in offline_archs],
+                      **{**DEMO_SHAPE, **kw})
+
+
+def print_device_bytes(node: NodeOrchestrator) -> int:
+    """Print the weight and KV-pool bytes each engine holds on its devices
+    (every engine keeps a KV array over the whole pool); returns the sum."""
+    total = 0
+    for name, eng in node.names.items():
+        params, cache = (sum(x.nbytes for x in jax.tree.leaves(t))
+                         for t in (eng.params, eng.cache))
+        total += params + cache
+        print(f'{name}: params {params / 2**30:.3f} GiB, '
+              f'KV cache {cache / 2**30:.3f} GiB')
+    print(f'device bytes held by the node, all devices: '
+          f'{total / 2**30:.3f} GiB')
+    return total
 
 
 def serve_demo(*, arch: str = 'qwen3-0.6b',
@@ -80,17 +129,18 @@ def serve_demo(*, arch: str = 'qwen3-0.6b',
                quiet: bool = False, max_prefill_reqs: int = 4,
                piggyback_decode: bool = True,
                node: Optional[NodeOrchestrator] = None):
-    """Drive the node for ``steps`` scheduler ticks; returns metrics.
+    """Drive the reduced-width demo node for ``steps`` scheduler ticks;
+    returns metrics.
 
     A prebuilt ``node`` takes precedence: the build kwargs (``arch``,
     ``offline_archs``, ``max_prefill_reqs``, ``piggyback_decode``,
     ``clock``) only apply when this function builds the node itself.
     """
     rng = np.random.default_rng(seed)
-    node = node or build_node(arch=arch, offline_archs=offline_archs,
-                              seed=seed, clock=clock,
-                              max_prefill_reqs=max_prefill_reqs,
-                              piggyback_decode=piggyback_decode)
+    node = node or demo_node(arch=arch, offline_archs=offline_archs,
+                             seed=seed, clock=clock,
+                             max_prefill_reqs=max_prefill_reqs,
+                             piggyback_decode=piggyback_decode)
     online_eng = node.online
 
     # offline backlog: long prompts, long generations, spread round-robin
@@ -149,7 +199,9 @@ def serve_http(*, arch: str = 'qwen3-0.6b',
     from repro.serving.frontend.driver import AsyncNodeDriver
     from repro.serving.frontend.http import serve_asgi
 
-    node = build_node(arch=arch, offline_archs=offline_archs, seed=seed)
+    node = build_node(get_config(arch),
+                      [get_config(a) for a in offline_archs], seed=seed)
+    print_device_bytes(node)
 
     async def _main() -> None:
         async with AsyncNodeDriver(node) as driver:
@@ -185,6 +237,7 @@ def main():
     ap.add_argument('--port', type=int, default=8080)
     args = ap.parse_args()
     offline_archs = tuple(args.offline_arch or DEFAULT_OFFLINE_ARCHS)
+    enable_compile_cache()
     if args.http:
         serve_http(arch=args.arch, offline_archs=offline_archs,
                    host=args.host, port=args.port, seed=args.seed)

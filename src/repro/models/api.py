@@ -49,8 +49,8 @@ class Model:
     def template(self):
         return self.mod.template(self.cfg)
 
-    def init_params(self, rng):
-        return cm.init_from_template(self.template(), rng)
+    def init_params(self, rng, shardings=None):
+        return cm.init_from_template(self.template(), rng, shardings)
 
     def param_shapes(self):
         return cm.shapes_from_template(self.template())
@@ -143,9 +143,21 @@ class Model:
     def cache_axes(self, shape: ShapeConfig, **kw):
         return cm.axes_from_template(self.cache_template(shape, **kw))
 
-    def init_cache(self, shape: ShapeConfig, **kw):
+    def init_cache(self, shape: ShapeConfig, *, shardings=None, **kw):
         return cm.init_from_template(self.cache_template(shape, **kw),
-                                     jax.random.PRNGKey(0))
+                                     jax.random.PRNGKey(0), shardings)
+
+    def serve_shardings(self, mesh, engine_pages: int):
+        """(params, engine KV pool) shardings on ``mesh`` under
+        SERVE_RULES, resolved against the shapes so indivisible dims
+        relocate instead of failing."""
+        from repro.distributed.sharding import SERVE_RULES, tree_spec_shaped
+        cache = dict(engine_pages=engine_pages)
+        return (tree_spec_shaped(self.param_axes(), self.param_shapes(),
+                                 SERVE_RULES, mesh),
+                tree_spec_shaped(self.cache_axes(None, **cache),
+                                 self.cache_shapes(None, **cache),
+                                 SERVE_RULES, mesh))
 
     def enc_len(self, shape: ShapeConfig) -> int:
         """Encoder context for enc-dec shapes (see DESIGN.md)."""

@@ -12,6 +12,7 @@ sharding axes, and init law — from which we derive:
 from __future__ import annotations
 
 import dataclasses
+import functools
 from dataclasses import dataclass
 from typing import Any, Optional, Tuple
 
@@ -41,21 +42,31 @@ def _is_pspec(x):
     return isinstance(x, PSpec)
 
 
-def init_from_template(tmpl, rng: jax.Array):
+def _init_leaf(leaf: PSpec, key: jax.Array):
+    if leaf.init == 'zeros':
+        return jnp.zeros(leaf.shape, leaf.dtype)
+    if leaf.init == 'ones':
+        return jnp.ones(leaf.shape, leaf.dtype)
+    fan_in = leaf.shape[-2] if len(leaf.shape) >= 2 else leaf.shape[-1]
+    scale = leaf.scale if leaf.scale is not None else fan_in ** -0.5
+    return (jax.random.normal(key, leaf.shape, jnp.float32) * scale
+            ).astype(leaf.dtype)
+
+
+def init_from_template(tmpl, rng: jax.Array, shardings=None):
+    """Materialize a template.  With ``shardings`` (a matching tree of
+    ``jax.sharding.Sharding``) each leaf is generated by its own jitted
+    program straight into its sharding, so a model too large for one
+    device never exists whole on any device."""
     leaves, treedef = jax.tree.flatten(tmpl, is_leaf=_is_pspec)
     keys = jax.random.split(rng, len(leaves))
-    out = []
-    for leaf, key in zip(leaves, keys):
-        if leaf.init == 'zeros':
-            arr = jnp.zeros(leaf.shape, leaf.dtype)
-        elif leaf.init == 'ones':
-            arr = jnp.ones(leaf.shape, leaf.dtype)
-        else:
-            fan_in = leaf.shape[-2] if len(leaf.shape) >= 2 else leaf.shape[-1]
-            scale = leaf.scale if leaf.scale is not None else fan_in ** -0.5
-            arr = (jax.random.normal(key, leaf.shape, jnp.float32) * scale
-                   ).astype(leaf.dtype)
-        out.append(arr)
+    if shardings is None:
+        out = [_init_leaf(leaf, key) for leaf, key in zip(leaves, keys)]
+    else:
+        out = [jax.jit(functools.partial(_init_leaf, leaf),
+                       out_shardings=sh)(key)
+               for leaf, key, sh in zip(leaves, keys,
+                                        treedef.flatten_up_to(shardings))]
     return jax.tree.unflatten(treedef, out)
 
 

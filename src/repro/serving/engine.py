@@ -155,22 +155,21 @@ class Engine:
                 self.cfg.klass, on_invalidate=self.on_pages_invalidated)
         else:
             self.session = PoolSession(self.pool, self.cfg.klass)
-        self.cache = model.init_cache(None, engine_pages=self.pool.n_pages)
         # tensor-parallel plane: commit params and KV cache to their
         # SERVE_RULES shardings up front so every dispatch compiles against
-        # stable shardings (no per-call input resharding / signature churn)
+        # stable shardings (no per-call input resharding / signature churn).
+        # The cache is generated straight into its sharding; params built
+        # that way (NodeOrchestrator.add_engine) make the put a no-op.
         self.mesh = self.cfg.mesh
         self._c_sharding = None
-        if self.mesh is not None:
-            from repro.distributed.sharding import (SERVE_RULES,
-                                                    tree_spec_shaped)
-            p_sh = tree_spec_shaped(model.param_axes(), self.params,
-                                    SERVE_RULES, self.mesh)
-            self._c_sharding = tree_spec_shaped(
-                model.cache_axes(None, engine_pages=self.pool.n_pages),
-                self.cache, SERVE_RULES, self.mesh)
+        if self.mesh is None:
+            self.cache = model.init_cache(None, engine_pages=self.pool.n_pages)
+        else:
+            p_sh, self._c_sharding = model.serve_shardings(
+                self.mesh, self.pool.n_pages)
             self.params = jax.device_put(self.params, p_sh)
-            self.cache = jax.device_put(self.cache, self._c_sharding)
+            self.cache = model.init_cache(None, engine_pages=self.pool.n_pages,
+                                          shardings=self._c_sharding)
         self.pg = self.mcfg.page_size
         self.maxp = self.cfg.max_seq // self.pg
         self.requests: Dict[str, Request] = {}
@@ -187,6 +186,8 @@ class Engine:
         if decode_kernel is None:
             decode_kernel = (jax.default_backend() == 'tpu'
                              and self.mesh is None)
+        # the resolved decode attention path (True = Pallas paged kernel)
+        self.decode_kernel = decode_kernel
         # donate the KV cache buffers to the jitted step so the pools
         # update in place (donation is a no-op on CPU and would only warn)
         donate = (1,) if jax.default_backend() in ('tpu', 'gpu') else ()

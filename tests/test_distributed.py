@@ -26,6 +26,7 @@ def _run(code: str, devices: int = 8, timeout: int = 420) -> str:
 def test_train_step_sharded_matches_meshless():
     out = _run('''
         import jax, numpy as np, jax.numpy as jnp
+        from repro.launch.mesh import make_mesh
         from repro.configs import get_config, reduced
         from repro.configs.base import ShapeConfig
         from repro.models.api import build_model
@@ -33,7 +34,7 @@ def test_train_step_sharded_matches_meshless():
         from repro.training.train_step import make_train_step
         from repro.training.data import DataConfig, batch_at
 
-        mesh = jax.make_mesh((4, 2), ('data', 'model'))
+        mesh = make_mesh((4, 2), ('data', 'model'))
         cfg = reduced(get_config('qwen3-0.6b'))
         model = build_model(cfg)
         params = model.init_params(jax.random.PRNGKey(0))
@@ -64,12 +65,13 @@ def test_train_step_sharded_matches_meshless():
 def test_zero1_moments_sharded_over_data():
     out = _run('''
         import jax, numpy as np, jax.numpy as jnp
+        from repro.launch.mesh import make_mesh
         from repro.configs import get_config, reduced
         from repro.models.api import build_model
         from repro.training import optimizer as opt
         from repro.training.train_step import param_specs
 
-        mesh = jax.make_mesh((4, 2), ('data', 'model'))
+        mesh = make_mesh((4, 2), ('data', 'model'))
         cfg = reduced(get_config('internlm2-1.8b'), d_model=64, d_ff=256)
         model = build_model(cfg)
         pspec = param_specs(model, mesh)
@@ -87,10 +89,11 @@ def test_zero1_moments_sharded_over_data():
 def test_compressed_allreduce_matches_mean():
     out = _run('''
         import jax, numpy as np, jax.numpy as jnp
+        from repro.launch.mesh import make_mesh
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.training.compression import (init_error_state,
                                                 make_compressed_allreduce)
-        mesh = jax.make_mesh((8,), ('data',))
+        mesh = make_mesh((8,), ('data',))
         rng = np.random.default_rng(0)
         # global (8, 64) sharded over data: row i is device i's local grad
         g_global = rng.normal(size=(8, 64)).astype(np.float32)
@@ -121,10 +124,11 @@ def test_compressed_allreduce_matches_mean():
 def test_checkpoint_elastic_reshard():
     out = _run('''
         import jax, numpy as np, jax.numpy as jnp, tempfile
+        from repro.launch.mesh import make_mesh
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.training import checkpoint as ckpt
 
-        mesh_a = jax.make_mesh((4, 2), ('data', 'model'))
+        mesh_a = make_mesh((4, 2), ('data', 'model'))
         x = jnp.arange(64, dtype=jnp.float32).reshape(8, 8)
         xa = jax.device_put(x, NamedSharding(mesh_a, P('data', 'model')))
         d = tempfile.mkdtemp()
@@ -149,6 +153,7 @@ def test_elastic_failover_end_to_end():
     trajectory must match the unbroken run (data is step-pure)."""
     out = _run('''
         import jax, numpy as np, jax.numpy as jnp, tempfile
+        from repro.launch.mesh import make_mesh
         from repro.configs import get_config, reduced
         from repro.configs.base import ShapeConfig
         from repro.models.api import build_model
@@ -174,7 +179,7 @@ def test_elastic_failover_end_to_end():
             return params, state, losses
 
         # unbroken reference on the full mesh
-        mesh_a = jax.make_mesh((4, 2), ('data', 'model'))
+        mesh_a = make_mesh((4, 2), ('data', 'model'))
         sb, _ = make_train_step(model, mesh_a, opt_cfg=ocfg, donate=False)
         step_a = sb(shape)
         p0 = model.init_params(jax.random.PRNGKey(0))
@@ -223,12 +228,12 @@ def test_serve_step_lowers_on_small_mesh():
     out = _run('''
         import jax
         from repro.configs import get_config, SHAPES
-        from repro.kernels.common import cost_analysis_dict
+        from repro.launch.mesh import make_mesh
         from repro.models.api import build_model
         from repro.training.train_step import make_serve_step
         from repro.configs.base import ShapeConfig
 
-        mesh = jax.make_mesh((2, 4), ('data', 'model'))
+        mesh = make_mesh((2, 4), ('data', 'model'))
         cfg = get_config('qwen3-0.6b')
         model = build_model(cfg)
         shape = ShapeConfig('decode_small', 2048, 8, 'decode')
@@ -237,7 +242,7 @@ def test_serve_step_lowers_on_small_mesh():
                                model.cache_shapes(shape),
                                model.input_specs(shape))
         compiled = lowered.compile()
-        print('flops', cost_analysis_dict(compiled).get('flops', 0.0) > 0)
+        print('flops', (compiled.cost_analysis() or {}).get('flops', 0.0) > 0)
         print('OK')
     ''')
     assert 'flops True' in out   # cost analysis must actually report flops
