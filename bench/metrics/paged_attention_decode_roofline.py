@@ -1,0 +1,45 @@
+"""Kernels: the Pallas paged-decode attention kernel's share of its
+roofline in the traced stretch, in %.
+
+Ideal time: for every kernel call (one per layer of every pure-decode
+step of either engine; both engines' calls have the same shapes), the
+larger of its operations over the bf16 peak and its bytes over the HBM
+bandwidth (``flops.paged_decode_call``: q, out, and the K and V of each
+row's live tokens, not the pages the kernel walks).  Device time: the
+summed duration of the kernel's ops in the trace.  Moves
+``tpot_p90_ms``."""
+import sys
+
+import devtrace
+import flops
+
+# the kernel's op in the trace: ``%paged_attention.<n> = ... custom-call``
+KERNEL = '%paged_attention'
+
+
+def is_kernel(op_name: str) -> bool:
+    return op_name == KERNEL or op_name.startswith(KERNEL + '.')
+
+
+def read(run):
+    if run.trace is None or run.peaks is None:
+        return None
+    calls, device_s = devtrace.kernel_time(run.trace, is_kernel)
+    steps = run.steps_in(*run.traced, kind='decode')
+    if not calls or not steps:
+        return None
+    ideal, mem_bound = 0.0, 0
+    expected = 0
+    for s in steps:
+        hf = run.engines[s.engine]
+        f, b = flops.paged_decode_call(hf, s.live)
+        tf = f / run.peaks['bf16_flops_per_s']
+        tb = b / run.peaks['hbm_bytes_per_s']
+        layers = flops.dims(hf)['L']
+        ideal += layers * max(tf, tb)
+        mem_bound += layers * (tb >= tf)
+        expected += layers
+    print(f'paged_attention_decode_roofline: {calls} kernel calls in the trace, '
+          f'{expected} from {len(steps)} decode steps; bound by memory in '
+          f'{mem_bound} of {expected}', file=sys.stderr)
+    return 100.0 * ideal / device_s
