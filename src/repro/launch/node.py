@@ -147,6 +147,7 @@ class NodeOrchestrator:
                        f'#{len(self.names)}'
         assert name not in self.names, f'duplicate engine name {name!r}'
         self.names[name] = engine
+        engine.rename(name)         # its spans carry the node's key
         return engine
 
     def add_engine(self, model_cfg, engine_cfg: EngineConfig, *,

@@ -38,6 +38,7 @@ import numpy as np
 
 from repro.core.api import PoolSession
 from repro.core.clock import RealClock
+from repro.core.trace import span
 from repro.kernels.paged_attention.prefix import build_shared_runs
 from repro.serving.kvpool import QUARANTINE_PAGE
 from repro.serving.sampler import sample
@@ -47,6 +48,12 @@ from repro.serving.scheduler import (
 
 # re-exported for compatibility: request bookkeeping moved to scheduler.py
 __all__ = ['Engine', 'EngineConfig', 'EngineStats', 'Request', 'ReqState']
+
+# the phases of a step, each a span ``engine.step.<phase>:<engine name>``:
+# batch composition and admission, filling and staging the host buffers,
+# the jitted call, the host's wait for the sampled tokens, and appending
+# them to the requests
+PHASES = ('schedule', 'stage', 'launch', 'sync', 'commit')
 
 # jaxlib 0.4.3x CPU async dispatch intermittently corrupts the fused
 # lazy-token chain (sampled tokens feeding the next dispatch on-device with
@@ -127,6 +134,13 @@ class EngineStats:
     cancellations: int = 0          # requests abandoned before finishing
     token_flushes: int = 0          # lazy device→host token syncs (fused path)
     shared_page_reads_saved: int = 0  # page reads deduped by prefix sharing
+    # time to first token split at the engine, added once per request:
+    # Σ (launch of its first dispatch − submit) over ``queued`` requests,
+    # and Σ (first token − that launch) over ``prefilled`` requests
+    queue_wait_s: float = 0.0
+    queued: int = 0
+    prefill_s: float = 0.0
+    prefilled: int = 0
 
 
 class Engine:
@@ -179,6 +193,7 @@ class Engine:
         self.queue: List[str] = self.sched.queue
         self.running: List[str] = self.sched.running
         self.stats = EngineStats()
+        self.rename(f'{self.cfg.klass}:{self.mcfg.name}')
         self._key = jax.random.PRNGKey(self.cfg.seed)
         assert self.mcfg.family in ('dense', 'vlm', 'moe'), \
             'engine serves paged-KV decoder-only families'
@@ -248,6 +263,12 @@ class Engine:
         self._prev_tokens = jnp.zeros((self.cfg.max_batch,), jnp.int32)
         self._prev_rows: Dict[str, int] = {}
         self._seed_ctr = itertools.count()
+
+    def rename(self, name: str) -> None:
+        """Name the engine (a node gives it its key in ``names``) and
+        build the names of its step-phase spans."""
+        self.name = name
+        self._spans = {p: f'engine.step.{p}:{name}' for p in PHASES}
 
     def _init_buffers(self) -> None:
         """Preallocate the fixed-shape host staging buffers (one mixed
@@ -404,6 +425,22 @@ class Engine:
         # newest-output row map dies with this dispatch (rows resample)
         self.flush_tokens()
         self._prev_rows = {}
+        with span(self._spans['stage']):
+            mb = self._stage_mixed(batch)
+        self.session.iteration_start()                      # VALVE-SESSION
+        with span(self._spans['launch'],
+                  rows=len(batch.prefill) + len(batch.decode),
+                  prefill=' '.join(ps.req_id for ps in batch.prefill)):
+            self._count_queue_wait(batch.prefill)
+            self.cache, logits = self._mixed(self.params, self.cache, mb)
+        self.session.iteration_end()                        # VALVE-SESSION
+        with span(self._spans['sync']):
+            new = np.asarray(self._sample(logits))
+        with span(self._spans['commit']):
+            self._commit_mixed(batch, new)
+
+    def _stage_mixed(self, batch: ScheduledBatch) -> dict:
+        """Fill the mixed dispatch's host buffers and stage them."""
         m = self._mix
         m['toks'].fill(0)
         m['poss'].fill(0)
@@ -439,7 +476,7 @@ class Engine:
             m['kv_len'][row] = pos + 1
             m['last_idx'][row] = 0
             row += 1
-        mb = {
+        return {
             'tokens': jnp.asarray(m['toks']),
             'positions': jnp.asarray(m['poss']),
             'page_table': jnp.asarray(m['pts']),
@@ -448,15 +485,25 @@ class Engine:
             'kv_len': jnp.asarray(m['kv_len']),
             'last_idx': jnp.asarray(m['last_idx']),
         }
-        self.session.iteration_start()                      # VALVE-SESSION
-        self.cache, logits = self._mixed(self.params, self.cache, mb)
-        self.session.iteration_end()                        # VALVE-SESSION
+
+    def _count_queue_wait(self, prefill) -> None:
+        """At the launch of the first dispatch carrying a request (a
+        re-admission after an invalidation is not counted again)."""
+        now = self.clock.now()
+        for ps in prefill:
+            req = self.requests[ps.req_id]
+            if req.t_first_dispatch is None:
+                req.t_first_dispatch = now
+                self.stats.queue_wait_s += now - req.t_submit
+                self.stats.queued += 1
+
+    def _commit_mixed(self, batch: ScheduledBatch, new: np.ndarray) -> None:
+        """Record a mixed dispatch's sampled tokens and fill progress."""
         self.stats.dispatches += 1
         self.stats.mixed_dispatches += 1
         self.stats.prefill_chunks += len(batch.prefill)
         if batch.decode:
             self.stats.decode_iterations += 1
-        new = np.asarray(self._sample(logits))
         row = 0
         for ps in batch.prefill:
             req = self.requests[ps.req_id]
@@ -492,6 +539,36 @@ class Engine:
             # a slot's pending token predates the newest device array (the
             # request sat out a step): resolve to host values once
             self.flush_tokens()
+        with span(self._spans['stage']):
+            db = self._stage_decode(slots)
+        self.session.iteration_start()                      # VALVE-SESSION
+        with span(self._spans['launch'], rows=len(slots)):
+            if fused:
+                self.cache, toks = self._fused_decode(self.params, self.cache,
+                                                      db)
+            else:
+                self.cache, logits = self._decode(self.params, self.cache, db)
+        self.session.iteration_end()                        # VALVE-SESSION
+        self.stats.dispatches += 1
+        self.stats.decode_iterations += 1
+        if not fused:
+            with span(self._spans['sync']):
+                new = np.asarray(self._sample(logits))
+            with span(self._spans['commit']):
+                for i, ds in enumerate(slots):
+                    req = self.requests[ds.req_id]
+                    req.decode_steps += 1
+                    self._append_token(req, int(new[i]))
+            return
+        if self._cpu_step_sync:
+            with span(self._spans['sync']):
+                jax.block_until_ready(toks)  # see module header: dispatch race
+        with span(self._spans['commit']):
+            self._commit_fused(slots, toks)
+
+    def _stage_decode(self, slots: List[DecodeSlot]) -> dict:
+        """Fill the decode dispatch's host buffers and stage them."""
+        fused = self.cfg.fused_sampling
         d = self._dec
         d['toks'].fill(0)
         d['poss'].fill(0)
@@ -571,23 +648,11 @@ class Engine:
                 db['seed'] = st['seed0']
         else:
             db['tokens'] = jnp.asarray(d['toks'])
-        self.session.iteration_start()                      # VALVE-SESSION
-        if fused:
-            self.cache, toks = self._fused_decode(self.params, self.cache, db)
-        else:
-            self.cache, logits = self._decode(self.params, self.cache, db)
-        self.session.iteration_end()                        # VALVE-SESSION
-        self.stats.dispatches += 1
-        self.stats.decode_iterations += 1
-        if not fused:
-            new = np.asarray(self._sample(logits))
-            for i, ds in enumerate(slots):
-                req = self.requests[ds.req_id]
-                req.decode_steps += 1
-                self._append_token(req, int(new[i]))
-            return
-        if self._cpu_step_sync:
-            jax.block_until_ready(toks)  # see module header: dispatch race
+        return db
+
+    def _commit_fused(self, slots: List[DecodeSlot], toks) -> None:
+        """Record a fused decode dispatch's tokens as placeholders (and,
+        with an eos token, resolve them for the stop check)."""
         if hasattr(toks, 'copy_to_host_async'):
             toks.copy_to_host_async()   # overlap the eventual flush
         records: List[tuple] = []
@@ -625,13 +690,21 @@ class Engine:
         records.append((req.req_id, len(req.generated) - 1, row))
         if req.lease is not None:
             req.lease.note_filled(len(req.context) - 1)
+        self._stamp_token(req)
+        if len(req.generated) >= req.max_new_tokens:
+            self._finish(req)
+
+    def _stamp_token(self, req: Request) -> None:
+        """Time and count one new token; the first closes the request's
+        prefill (``prefill_s``: its first dispatch's launch → now)."""
         now = self.clock.now()
         if req.t_first_token is None:
             req.t_first_token = now
+            if req.t_first_dispatch is not None:
+                self.stats.prefill_s += now - req.t_first_dispatch
+                self.stats.prefilled += 1
         req.t_last_token = now
         self.stats.tokens_generated += 1
-        if len(req.generated) >= req.max_new_tokens:
-            self._finish(req)
 
     def flush_tokens(self) -> None:
         """Resolve lazily-held sampled tokens to host ints (fused path).
@@ -643,10 +716,11 @@ class Engine:
         invoke it unconditionally."""
         if not self._pending:
             return
-        for arr, records in self._pending:
-            vals = np.asarray(arr)
-            for rid, gi, row in records:
-                self.requests[rid].generated[gi] = int(vals[row])
+        with span(self._spans['sync']):
+            for arr, records in self._pending:
+                vals = np.asarray(arr)
+                for rid, gi, row in records:
+                    self.requests[rid].generated[gi] = int(vals[row])
         self._pending.clear()
         self._pending_rids.clear()
         self.stats.token_flushes += 1
@@ -656,11 +730,7 @@ class Engine:
         if req.lease is not None:
             # KV is materialized for every context token but the new one
             req.lease.note_filled(len(req.context) - 1)
-        now = self.clock.now()
-        if req.t_first_token is None:
-            req.t_first_token = now
-        req.t_last_token = now
-        self.stats.tokens_generated += 1
+        self._stamp_token(req)
         done = (len(req.generated) >= req.max_new_tokens
                 or (self.cfg.eos_token is not None
                     and tok == self.cfg.eos_token))
@@ -670,12 +740,13 @@ class Engine:
     # ------------------------------------------------------------------
     def step(self) -> bool:
         """One scheduling step; returns True if any dispatch happened."""
-        if self._gated():
-            self.stats.blocked_dispatches += 1
-            return False
-        batch = self.sched.schedule(self.requests, self._try_admit,
-                                    self._spill)
-        self.stats.steps += 1
+        with span(self._spans['schedule']):
+            if self._gated():
+                self.stats.blocked_dispatches += 1
+                return False
+            batch = self.sched.schedule(self.requests, self._try_admit,
+                                        self._spill)
+            self.stats.steps += 1
         if batch.empty:
             return False
         if batch.prefill:

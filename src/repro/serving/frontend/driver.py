@@ -30,12 +30,18 @@ import asyncio
 from dataclasses import dataclass
 from typing import Dict, List, NamedTuple, Optional, Sequence
 
+from repro.core.trace import span
 from repro.launch.node import NodeOrchestrator
 from repro.serving.frontend.batches import BatchManager
 from repro.serving.scheduler import ReqState
 
 __all__ = ['AsyncNodeDriver', 'OnlineStream', 'TokenEvent', 'DriverStats',
            'clock_sleep']
+
+# spans of the pump: one turn (node steps, stream deltas, batch polls),
+# and a zero-length mark where the idle pump parks on its wake event
+PUMP_SPAN = 'driver.pump'
+PARK_SPAN = 'driver.park'
 
 
 async def clock_sleep(clock, dt: float) -> None:
@@ -110,8 +116,9 @@ class AsyncNodeDriver:
         self.clock = node.clock
         self.batches = BatchManager(node)
         self.stats = DriverStats()
-        # ≥1 node steps per loop yield: raising this trades intake latency
-        # for pump throughput under heavy traffic (benchmarked, not guessed)
+        # ≥1 node steps per loop yield: raising this lengthens each pump
+        # turn, which every intake and SSE write waits behind, for fewer
+        # loop passes (the ``driver.pump`` spans time the turns)
         self.ticks_per_yield = max(1, int(ticks_per_yield))
         self._streams: Dict[str, OnlineStream] = {}
         self._wake = asyncio.Event()
@@ -232,8 +239,18 @@ class AsyncNodeDriver:
                     continue        # a submit raced the clear (same task
                                     # can't, but a re-kick costs nothing)
                 self.stats.idle_parks += 1
+                with span(PARK_SPAN):
+                    pass
                 await self._wake.wait()
                 continue
+            self._turn()
+            # hand the loop to intake / SSE writers between dispatches
+            await asyncio.sleep(0)
+
+    def _turn(self) -> None:
+        """One pump turn, which holds the event loop: up to
+        ``ticks_per_yield`` node steps, then stream deltas and batch polls."""
+        with span(PUMP_SPAN):
             for _ in range(self.ticks_per_yield):
                 if not self._has_work():
                     break
@@ -241,8 +258,6 @@ class AsyncNodeDriver:
                 self.stats.ticks += 1
             self._flush_streams()
             self.batches.poll()
-            # hand the loop to intake / SSE writers between dispatches
-            await asyncio.sleep(0)
 
     async def drain(self, max_ticks: int = 100_000) -> None:
         """Pump until the node is idle WITHOUT a running pump task (test
@@ -253,9 +268,6 @@ class AsyncNodeDriver:
                 self._flush_streams()
                 self.batches.poll()
                 return
-            self.node.step()
-            self.stats.ticks += 1
-            self._flush_streams()
-            self.batches.poll()
+            self._turn()
             await asyncio.sleep(0)
         raise RuntimeError('drain exceeded max_ticks')

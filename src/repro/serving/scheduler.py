@@ -55,6 +55,8 @@ class Request:
     recomputes: int = 0
     blocked_admits: int = 0       # consecutive failed admission attempts
     t_submit: float = 0.0
+    # the launch of the first dispatch that carried the request's prefill
+    t_first_dispatch: Optional[float] = None
     t_first_token: Optional[float] = None
     t_last_token: Optional[float] = None
     decode_steps: int = 0
