@@ -186,8 +186,8 @@ def serve_http(*, arch: str = 'qwen3-0.6b',
                seed: int = 0) -> None:
     """Run the async serving front-end over a live node: OpenAI-style
     ``POST /v1/completions`` (SSE streaming) + the ``/v1/batches`` offline
-    batch-job API, one event loop owning the runtime (docs/API.md
-    § Serving endpoints).
+    batch-job API, one event loop owning the front end and one worker
+    thread the node's steps (docs/API.md § Serving endpoints).
 
         PYTHONPATH=src python -m repro.launch.serve --http --port 8080
         curl -N localhost:8080/v1/completions -d \\

@@ -1,7 +1,8 @@
 """Async serving front-end over :class:`~repro.launch.node.NodeOrchestrator`.
 
-One event loop owns the runtime (:class:`AsyncNodeDriver` pumps
-``node.step()`` cooperatively with request intake); the HTTP surface is a
+One event loop owns the front end (:class:`AsyncNodeDriver` pumps
+``node.step()``, on one worker thread under a real clock, while request
+intake and SSE writers run on the loop); the HTTP surface is a
 framework-free ASGI app (:class:`FrontendApp`) with an OpenAI-style
 streaming online API (``POST /v1/completions`` + SSE) and an offline
 batch-job API (``POST /v1/batches`` submit → poll → fetch).  See

@@ -1,14 +1,32 @@
-"""AsyncNodeDriver — one event loop owns the runtime.
+"""AsyncNodeDriver — the event loop owns the front end; one worker thread
+runs the node's steps.
 
 The serving front-end's execution model: a single asyncio task pumps
-``NodeOrchestrator.step()`` cooperatively with request intake (no
-thread-per-request, no locks — every handler and the pump interleave at
-``await`` points on one loop).  The pump yields to the loop after every
-node tick, so SSE writers flush token deltas and new submissions land
-between dispatches; when the node goes idle it parks on an event and is
-kicked by the next submission, burning neither CPU nor virtual time.
+``NodeOrchestrator.step()`` while request intake and SSE writers run on
+the same event loop (no thread-per-request).  Under a real clock the pump
+hands each turn's node steps to the driver's one worker thread and awaits
+them, so the loop stays free while the host waits on the device: an
+arrival reaches ``submit`` and a token reaches the wire within the step in
+flight, not several steps later.  Two threads, two kinds of state:
 
-Token delivery is a *tap*, not an engine hook: after each tick the driver
+- only the worker runs node steps, and only while the pump awaits it;
+- only the loop's thread touches asyncio objects (streams, the wake
+  event), flushes stream deltas and polls batch jobs, between steps.
+
+A write to node state from the loop (a stream's submit or cancel, a batch
+job's submit or cancel) made while a step is in flight is *held*: the
+pump applies it on the loop's thread right after that step, before the
+next one.  Request ids are minted at the call, so a held submit still
+returns its final id, and its ``t_submit`` is the call's time.  Reads
+(metrics, health, batch status) may run at any time.  When the pump is
+parked, writes apply at once and kick it.
+
+Under a :class:`~repro.core.clock.VirtualClock` the pump keeps the
+in-loop turn (node steps, deltas and polls on the loop, then one yield):
+virtual time has no device wait to overlap, and the protocol tests rely
+on its determinism.  ``drain()`` is in-loop under either clock.
+
+Token delivery is a *tap*, not an engine hook: after each turn the driver
 diffs every streamed request's ``generated`` list against what its
 :class:`OnlineStream` has already emitted and pushes the deltas.  The
 engine (and the Valve patch surface) stays untouched — streaming is a
@@ -27,19 +45,22 @@ load generator are deterministic and never wall-clock sleep.
 from __future__ import annotations
 
 import asyncio
+import functools
+from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass
-from typing import Dict, List, NamedTuple, Optional, Sequence
+from typing import Callable, Dict, List, NamedTuple, Optional, Sequence
 
 from repro.core.trace import span
 from repro.launch.node import NodeOrchestrator
-from repro.serving.frontend.batches import BatchManager
-from repro.serving.scheduler import ReqState
+from repro.serving.frontend.batches import (
+    _TERMINAL, BatchItem, BatchJob, BatchManager)
+from repro.serving.scheduler import ReqState, Request
 
 __all__ = ['AsyncNodeDriver', 'OnlineStream', 'TokenEvent', 'DriverStats',
            'clock_sleep']
 
-# spans of the pump: one turn (node steps, stream deltas, batch polls),
-# and a zero-length mark where the idle pump parks on its wake event
+# spans of the pump: one turn (node steps; in the loop, also stream deltas
+# and batch polls), and a zero-length mark where the idle pump parks
 PUMP_SPAN = 'driver.pump'
 PARK_SPAN = 'driver.park'
 
@@ -104,26 +125,85 @@ class DriverStats:
     streams_finished: int = 0
     streams_cancelled: int = 0
     idle_parks: int = 0              # pump waits for a kick
+    turns_off_loop: int = 0          # turns whose steps ran on the worker
+    deferred: int = 0                # node writes held for a step in flight
+
+
+class _HeldItem(BatchItem):
+    """A batch item whose engine submit may still be held for the step in
+    flight; until it lands the item reads as a queued request."""
+
+    @property
+    def request(self) -> Request:
+        return (self.engine.requests.get(self.req_id)
+                or Request(self.req_id, self.prompt, self.max_new_tokens))
+
+
+class _DriverBatches(BatchManager):
+    """The driver's batch jobs: the engine writes of a submit or a cancel
+    go through :meth:`AsyncNodeDriver._write`, so one made while a step is
+    in flight is held until that step ends.  Ids are minted and a job's
+    status settles at the call, so a job reads the same either way."""
+
+    def __init__(self, driver: 'AsyncNodeDriver'):
+        super().__init__(driver.node)
+        self._write = driver._write
+
+    def submit(self, requests: Sequence[dict]) -> BatchJob:
+        offline = self.node.offline
+        assert offline, 'node has no offline engines'
+        assert requests, 'empty batch'
+        items: List[BatchItem] = []
+        for i, spec in enumerate(requests):
+            prompt = list(map(int, spec['prompt']))
+            max_new = int(spec.get('max_tokens', 16))
+            eng = offline[self._rr % len(offline)]
+            self._rr += 1
+            assert len(prompt) + max_new <= eng.cfg.max_seq, \
+                (len(prompt), max_new, eng.cfg.max_seq)
+            rid = eng.session.new_request_id()
+            self._write(functools.partial(eng.submit, prompt, max_new, rid))
+            items.append(_HeldItem(i, prompt, max_new, req_id=rid,
+                                   engine=eng))
+        job = BatchJob(f'batch-{next(self._seq)}', items,
+                       created_at=self.node.clock.now())
+        self.jobs[job.job_id] = job
+        return job
+
+    def cancel(self, job_id: str) -> Optional[BatchJob]:
+        job = self.jobs.get(job_id)
+        if job is not None and job.status not in _TERMINAL:
+            for it in job.items:
+                self._write(functools.partial(it.engine.cancel, it.req_id))
+            job.status = 'cancelled'
+            job.completed_at = self.node.clock.now()
+        return job
 
 
 class AsyncNodeDriver:
-    """Pumps one :class:`NodeOrchestrator` inside the event loop and
-    exposes async submission surfaces (online streams + batch jobs)."""
+    """Pumps one :class:`NodeOrchestrator` from the event loop (its steps
+    on one worker thread under a real clock) and exposes async submission
+    surfaces (online streams + batch jobs)."""
 
     def __init__(self, node: NodeOrchestrator, *,
                  ticks_per_yield: int = 1):
         self.node = node
         self.clock = node.clock
-        self.batches = BatchManager(node)
         self.stats = DriverStats()
-        # ≥1 node steps per loop yield: raising this lengthens each pump
-        # turn, which every intake and SSE write waits behind, for fewer
-        # loop passes (the ``driver.pump`` spans time the turns)
+        # ≥1 node steps per turn: raising this lengthens each turn, after
+        # which stream deltas, batch polls and held writes are applied
+        # (the ``driver.pump`` spans time the turns)
         self.ticks_per_yield = max(1, int(ticks_per_yield))
         self._streams: Dict[str, OnlineStream] = {}
         self._wake = asyncio.Event()
         self._task: Optional[asyncio.Task] = None
         self._stopping = False
+        # the worker that runs the node's steps (None: in-loop turns), and
+        # the writes held while a step is in flight on it
+        self._worker: Optional[ThreadPoolExecutor] = None
+        self._in_flight = False
+        self._held: List[Callable[[], object]] = []
+        self.batches = _DriverBatches(self)
 
     # ------------------------------------------------------------------
     # Lifecycle
@@ -136,36 +216,66 @@ class AsyncNodeDriver:
         await self.stop()
 
     def start(self) -> None:
-        """Start the pump task (must run inside the owning event loop)."""
+        """Start the pump task (must run inside the owning event loop);
+        under a real clock, also the worker thread for the node's steps."""
         assert self._task is None, 'driver already started'
         self._stopping = False
+        self._in_flight = False
+        if not getattr(self.clock, 'virtual', False):
+            self._worker = ThreadPoolExecutor(
+                max_workers=1, thread_name_prefix='node-step')
         self._task = asyncio.get_running_loop().create_task(self._pump())
 
     async def stop(self) -> None:
-        """Stop the pump (idempotent).  In-flight requests stay in the
-        engines; a restarted driver resumes them."""
+        """Stop the pump (idempotent): wait for the step in flight, then
+        shut the worker down.  In-flight requests stay in the engines; a
+        restarted driver resumes them.  A step's exception re-raises."""
         self._stopping = True
         self._wake.set()
-        if self._task is not None:
-            await self._task
-            self._task = None
+        try:
+            if self._task is not None:
+                await self._task
+                self._task = None
+        finally:
+            if self._worker is not None:
+                self._worker.shutdown(wait=True)
+                self._worker = None
 
     def kick(self) -> None:
         """Wake an idle pump (new work arrived)."""
         self._wake.set()
+
+    def _write(self, apply: Callable[[], object]) -> None:
+        """Apply a write to node state now, or hold it until the step in
+        flight ends (the pump applies held writes in order)."""
+        if self._in_flight:
+            self._held.append(apply)
+            self.stats.deferred += 1
+        else:
+            apply()
 
     # ------------------------------------------------------------------
     # Online streaming surface
     # ------------------------------------------------------------------
     def submit_stream(self, prompt: Sequence[int],
                       max_new_tokens: int = 32) -> OnlineStream:
-        """Submit one online request; returns its token stream."""
+        """Submit one online request; returns its token stream.  The id is
+        final at once; the engine submit may be held for the step in
+        flight, and ``t_submit`` is this call's time either way."""
         eng = self.node.online
         assert eng is not None, 'node has no online engine'
-        rid = eng.submit(list(prompt), max_new_tokens)
+        prompt, t = list(prompt), self.clock.now()
+        assert prompt and len(prompt) + max_new_tokens <= eng.cfg.max_seq, \
+            (len(prompt), max_new_tokens, eng.cfg.max_seq)
+        rid = eng.session.new_request_id()
+
+        def apply():
+            eng.submit(prompt, max_new_tokens, req_id=rid)
+            eng.requests[rid].t_submit = t
         stream = OnlineStream(self, rid)
         self._streams[rid] = stream
         self.stats.streams_opened += 1
+        self._write(apply)
         self.kick()
         return stream
 
@@ -183,7 +293,15 @@ class AsyncNodeDriver:
         """Cancel an online request (client disconnect path): the holding
         engine releases its lease immediately — on whichever pool the
         request sits, including mid-handoff — and the stream gets a
-        terminal ``cancelled`` event."""
+        terminal ``cancelled`` event.  While a step is in flight the
+        cancel is held until it ends, and this returns whether the driver
+        still streams the request (it may yet finish in that step)."""
+        if self._in_flight:
+            self._write(functools.partial(self._cancel, req_id))
+            return req_id in self._streams
+        return self._cancel(req_id)
+
+    def _cancel(self, req_id: str) -> bool:
         eng = self._engine_holding(req_id)
         cancelled = eng is not None and eng.cancel(req_id)
         if cancelled:
@@ -205,7 +323,9 @@ class AsyncNodeDriver:
             if id(eng) not in flushed:
                 flushed.add(id(eng))
                 eng.flush_tokens()   # resolve fused-path lazy tokens
-            req = eng.requests[rid]
+            req = eng.requests.get(rid)
+            if req is None:
+                continue            # its submit is held for the step
             while stream.emitted < len(req.generated):
                 stream._q.put_nowait(TokenEvent(
                     req.generated[stream.emitted], stream.emitted, None))
@@ -231,6 +351,7 @@ class AsyncNodeDriver:
         return self.node.has_work()
 
     async def _pump(self) -> None:
+        loop = asyncio.get_running_loop()
         while not self._stopping:
             if not self._has_work():
                 self._flush_streams()
@@ -243,19 +364,40 @@ class AsyncNodeDriver:
                     pass
                 await self._wake.wait()
                 continue
-            self._turn()
-            # hand the loop to intake / SSE writers between dispatches
-            await asyncio.sleep(0)
+            if self._worker is None:
+                self._turn()
+                # hand the loop to intake / SSE writers between dispatches
+                await asyncio.sleep(0)
+                continue
+            # the loop runs intake and SSE writers while the worker steps;
+            # node writes made meanwhile are held (``_write``)
+            self._in_flight = True
+            await loop.run_in_executor(self._worker, self._worker_turn)
+            self.stats.turns_off_loop += 1
+            self._flush_streams()
+            self.batches.poll()
+            self._in_flight = False
+            held, self._held = self._held, []
+            for apply in held:
+                apply()
+
+    def _steps(self) -> None:
+        for _ in range(self.ticks_per_yield):
+            if not self._has_work():
+                break
+            self.node.step()
+            self.stats.ticks += 1
+
+    def _worker_turn(self) -> None:
+        """One pump turn on the worker thread: the node's steps alone."""
+        with span(PUMP_SPAN):
+            self._steps()
 
     def _turn(self) -> None:
         """One pump turn, which holds the event loop: up to
         ``ticks_per_yield`` node steps, then stream deltas and batch polls."""
         with span(PUMP_SPAN):
-            for _ in range(self.ticks_per_yield):
-                if not self._has_work():
-                    break
-                self.node.step()
-                self.stats.ticks += 1
+            self._steps()
             self._flush_streams()
             self.batches.poll()
 
