@@ -6,43 +6,36 @@ tokens.  A step that skips padding or dead pages therefore reads closer to
 its peak, and none can read above it.
 
 A model here is the served config (``bench/configs``, Hugging Face keys).
+Its architecture's module, ``bench/archs/<architectures[0]>.py``, gives
+its parameters, its output head and the attention work of a step and of
+each paged-decode call; the steps are summed from them here.
 """
 from __future__ import annotations
 
-from typing import Iterable, Sequence, Tuple
+from typing import Iterable, List, Sequence, Tuple
 
-BF16 = 2
-
-
-def dims(hf: dict) -> dict:
-    d, h = hf['hidden_size'], hf['num_attention_heads']
-    hd = hf.get('head_dim') or d // h
-    return {'d': d, 'f': hf['intermediate_size'], 'h': h,
-            'hkv': hf['num_key_value_heads'], 'hd': hd,
-            'L': hf['num_hidden_layers'], 'V': hf['vocab_size']}
+import archs
 
 
 def layer_params(hf: dict) -> int:
-    """Matrix parameters of one layer (norm weights left out)."""
-    m = dims(hf)
-    q, kv = m['h'] * m['hd'], m['hkv'] * m['hd']
-    return m['d'] * (q + 2 * kv) + q * m['d'] + 3 * m['d'] * m['f']
+    """Matrix parameters of one layer (norm weights left out); the mean
+    over the layers where they differ."""
+    return body_params(hf) // hf['num_hidden_layers']
 
 
 def body_params(hf: dict) -> int:
-    """Non-embedding matrix parameters: every layer's."""
-    return dims(hf)['L'] * layer_params(hf)
+    """Non-embedding matrix parameters a token passes through."""
+    return archs.of(hf).body_params(hf)
 
 
 def attn_flops_per_key(hf: dict) -> int:
-    """QK^T and PV for one query against one key, summed over layers."""
-    m = dims(hf)
-    return 4 * m['h'] * m['hd'] * m['L']
+    """QK^T and PV for one query against one key, summed over the layers
+    that attend."""
+    return archs.of(hf).attn_flops(hf, (), (1,))
 
 
 def unembed_flops(hf: dict) -> int:
-    m = dims(hf)
-    return 2 * m['d'] * m['V']
+    return archs.of(hf).unembed_flops(hf)
 
 
 def decode_step_flops(hf: dict, live: Sequence[int]) -> int:
@@ -50,7 +43,7 @@ def decode_step_flops(hf: dict, live: Sequence[int]) -> int:
     row's attended tokens (its context, the new token included)."""
     rows = len(live)
     return (rows * (2 * body_params(hf) + unembed_flops(hf))
-            + attn_flops_per_key(hf) * sum(live))
+            + archs.of(hf).attn_flops(hf, (), live))
 
 
 def mixed_step_flops(hf: dict, prefill: Iterable[Tuple[int, int]],
@@ -61,19 +54,19 @@ def mixed_step_flops(hf: dict, prefill: Iterable[Tuple[int, int]],
     prefill = list(prefill)
     toks = sum(n for _, n in prefill) + len(decode_live)
     rows = len(prefill) + len(decode_live)
-    keys = sum(n * start + n * (n + 1) // 2 for start, n in prefill)
-    keys += sum(decode_live)
     return (2 * body_params(hf) * toks + unembed_flops(hf) * rows
-            + attn_flops_per_key(hf) * keys)
+            + archs.of(hf).attn_flops(hf, prefill, decode_live))
+
+
+def paged_decode_calls(hf: dict, live: Sequence[int]) -> List[Tuple[int, int]]:
+    """(flops, bytes) of every paged-decode attention call of one
+    pure-decode step over real rows with ``live`` tokens each: q in, out
+    back, and the K and V of the tokens each call attends to (not the
+    pages the kernel walks)."""
+    return archs.of(hf).paged_decode_calls(hf, live)
 
 
 def paged_decode_call(hf: dict, live: Sequence[int]) -> Tuple[int, int]:
-    """(flops, bytes) of one paged-decode attention call (one layer) over
-    real rows with ``live`` tokens each: q in, out back, and the K and V
-    of the live tokens (not the pages the kernel walks)."""
-    m = dims(hf)
-    rows = len(live)
-    flops = 4 * m['h'] * m['hd'] * sum(live)
-    qo = 2 * rows * m['h'] * m['hd'] * BF16
-    kv = 2 * sum(live) * m['hkv'] * m['hd'] * BF16
-    return flops, qo + kv
+    """(flops, bytes) of the step's largest paged-decode call: one layer's,
+    where every layer attends alike."""
+    return max(paged_decode_calls(hf, live))

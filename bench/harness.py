@@ -13,7 +13,8 @@ flush).  With ``trace`` the same spans also go into the profiler's trace.
 
 Everything a cell is made of is found by name: ``BENCHMARK.json`` →
 ``bench/configs/<config>.json``, ``bench/traffic/<mix>.json`` and
-``bench/metrics/<metric>.py``.
+``bench/metrics/<metric>.py``; each served model's program config,
+reference and counts → ``bench/archs/<architecture>.py``.
 """
 from __future__ import annotations
 
@@ -38,6 +39,7 @@ BENCH = Path(__file__).resolve().parent
 ROOT = BENCH.parent
 sys.path.insert(0, str(BENCH))
 
+import archs        # noqa: E402
 import client       # noqa: E402
 import e2e          # noqa: E402
 import reference    # noqa: E402
@@ -82,15 +84,12 @@ def load_cell(name: str) -> Cell:
 
 
 def model_config(entry: dict, page_size: int):
-    """The program's ModelConfig for one served model of a config file."""
+    """The program's ModelConfig for one served model of a config file, as
+    its architecture's module gives it."""
     from repro.configs import ModelConfig
-    s = reference.spec_of(entry['config'])
-    return ModelConfig(
-        name=entry['model'], family='dense', n_layers=s.layers,
-        d_model=s.d, n_heads=s.heads, n_kv_heads=s.kv_heads, d_ff=s.f,
-        vocab_size=s.vocab, head_dim=s.hd, qk_norm=s.qk_norm,
-        rope_theta=s.theta, norm_eps=s.eps, tie_embeddings=s.tied,
-        page_size=page_size)
+    hf = entry['config']
+    return ModelConfig(name=entry['model'],
+                       **archs.of(hf).program_config(hf, page_size))
 
 
 class GcClock:

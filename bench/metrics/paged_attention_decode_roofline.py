@@ -1,14 +1,14 @@
 """Kernels: the Pallas paged-decode attention kernel's share of its
 roofline in the traced stretch, in %.
 
-Ideal time: for every kernel call (one per layer of every pure-decode
-step of either engine; both engines' calls have the same shapes), the
+Ideal time: for every kernel call of every pure-decode step of either
+engine (``flops.paged_decode_calls``, from the model's architecture), the
 larger of its operations over the bf16 peak and its bytes over the HBM
-bandwidth (``flops.paged_decode_call``: q, out, and the K and V of each
-row's live tokens, not the pages the kernel walks).  Device time: the
-summed duration of the kernel's ops in the trace.  Moves
-``tpot_p90_ms``."""
+bandwidth (q, out, and the K and V of the tokens the call attends to, not
+the pages the kernel walks).  Device time: the summed duration of the
+kernel's ops in the trace.  Moves ``tpot_p90_ms``."""
 import sys
+from collections import Counter
 
 import devtrace
 import flops
@@ -31,14 +31,13 @@ def read(run):
     ideal, mem_bound = 0.0, 0
     expected = 0
     for s in steps:
-        hf = run.engines[s.engine]
-        f, b = flops.paged_decode_call(hf, s.live)
-        tf = f / run.peaks['bf16_flops_per_s']
-        tb = b / run.peaks['hbm_bytes_per_s']
-        layers = flops.dims(hf)['L']
-        ideal += layers * max(tf, tb)
-        mem_bound += layers * (tb >= tf)
-        expected += layers
+        step_calls = flops.paged_decode_calls(run.engines[s.engine], s.live)
+        for (f, b), n in Counter(step_calls).items():   # alike calls at once
+            tf = f / run.peaks['bf16_flops_per_s']
+            tb = b / run.peaks['hbm_bytes_per_s']
+            ideal += n * max(tf, tb)
+            mem_bound += n * (tb >= tf)
+            expected += n
     print(f'paged_attention_decode_roofline: {calls} kernel calls in the trace, '
           f'{expected} from {len(steps)} decode steps; bound by memory in '
           f'{mem_bound} of {expected}', file=sys.stderr)
