@@ -8,10 +8,14 @@ module imports nothing of the program and gives:
 - ``program_config(hf, page_size) -> dict``: the keyword arguments of the
   program's ``repro.configs.ModelConfig`` (its ``family`` included, its
   ``name`` left to the harness), from the model's Hugging Face config;
-- ``Reference(hf, seed)``, whose ``gaps(prompt, served, *, control=False)``
-  reads a served sequence against a plain float32 forward under
-  ``default_matmul_precision('highest')``, with weights made from the seed
-  by the family's law, and the W8A8 control (see ``bench/reference.py``);
+- ``Reference(hf, seed, mesh=None)``, whose ``gaps(prompt, served, *,
+  control=False)`` reads a served sequence against a plain float32 forward
+  under ``default_matmul_precision('highest')``, with weights made from the
+  seed by the family's law, and the W8A8 control (see
+  ``bench/reference.py``); with a ``jax.sharding.Mesh`` (the node's, where
+  the config declares one) the weights are laid over its devices, so a
+  model that fits the mesh and not one chip can be read, with the same
+  bits in the weights;
 - the counts the per-layer readers need (``bench/flops.py``), each a
   function of the Hugging Face config, each an exact integer:
   ``body_params(hf)``: matrix parameters a token passes through, outside

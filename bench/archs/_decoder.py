@@ -25,6 +25,7 @@ from dataclasses import dataclass
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 BUCKET = 512        # sequences pad to a multiple: one compile per bucket
 BF16 = 2            # bytes of a served weight, activation or KV entry
@@ -94,10 +95,13 @@ def param_shapes(s: Spec) -> dict:
     return dict(sorted(shapes.items()))
 
 
-def make_weights(shapes: dict, d: int, seed: int) -> dict:
+def make_weights(shapes: dict, d: int, seed: int, mesh=None) -> dict:
     """All weights of ``shapes`` (as :func:`param_shapes` gives them) in
     bfloat16, from the seed by the law above, in one jitted call; ``d`` is
-    the hidden size that scales the embedding."""
+    the hidden size that scales the embedding.  Over a ``mesh`` each
+    matrix's last axis is split over all its devices where it divides;
+    the rest, norm weights included, is replicated.  The bits are the
+    law's either way (jax's random bits do not depend on the sharding)."""
 
     def gen(key):
         keys = jax.random.split(key, len(shapes))
@@ -110,7 +114,20 @@ def make_weights(shapes: dict, d: int, seed: int) -> dict:
                 out[name] = (jax.random.normal(k, shape, jnp.float32)
                              * scale).astype(jnp.bfloat16)
         return out
-    return jax.jit(gen)(jax.random.PRNGKey(seed))
+    laid = {} if mesh is None else {'out_shardings': shardings(shapes, mesh)}
+    return jax.jit(gen, **laid)(jax.random.PRNGKey(seed))
+
+
+def shardings(shapes: dict, mesh) -> dict:
+    """name → ``NamedSharding``: a matrix's last axis over every axis of
+    ``mesh`` where the mesh's size divides it, else replicated."""
+    out = {}
+    for name, (shape, fan_in) in shapes.items():
+        split = fan_in is not None and shape[-1] % mesh.size == 0
+        spec = (P(*[None] * (len(shape) - 1), mesh.axis_names) if split
+                else P())
+        out[name] = NamedSharding(mesh, spec)
+    return out
 
 
 # ----------------------------------------------------------------- pieces
@@ -233,11 +250,13 @@ def gaps(forward, s, w, prompt, served, *, control: bool = False):
 
 class Reference:
     """A dense decoder's reference from its published config and the
-    seed."""
+    seed, its weights laid over ``mesh`` where one is given (the forward
+    then runs sharded; without one, on the default device)."""
 
-    def __init__(self, hf: dict, seed: int, *, qk_norm: bool):
+    def __init__(self, hf: dict, seed: int, mesh=None, *, qk_norm: bool):
         self.spec = spec_of(hf, qk_norm)
-        self.w = make_weights(param_shapes(self.spec), self.spec.d, seed)
+        self.w = make_weights(param_shapes(self.spec), self.spec.d, seed,
+                              mesh)
 
     def gaps(self, prompt, served, *, control: bool = False):
         return gaps(forward, self.spec, self.w, prompt, served,

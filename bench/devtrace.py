@@ -132,8 +132,9 @@ def idle_share(trace: Trace) -> float:
 
 
 def top_ops(trace: Trace, n: int = 10) -> List[list]:
-    """The device ops that took most time in the window, by op label; a
-    loop or call op, whose time is its body's, is left out."""
+    """The device ops that took most time in the window, by op label,
+    averaged over the devices traced (as :func:`busy_s`); a loop or call
+    op, whose time is its body's, is left out."""
     lo, hi = trace.window
     tot: Dict[str, int] = defaultdict(int)
     names, st, en = trace.ops()
@@ -142,12 +143,14 @@ def top_ops(trace: Trace, n: int = 10) -> List[list]:
         if e > s and not any(c in name for c in CONTAINERS):
             tot[op_label(name)] += e - s
     best = sorted(tot.items(), key=lambda kv: -kv[1])[:n]
-    return [[k, v * 1e-9] for k, v in best]
+    return [[k, v * 1e-9 / len(trace.devices)] for k, v in best]
 
 
 def idle_by_host(trace: Trace, n: int = 10) -> List[list]:
     """Idle device time in the window, by the innermost benchmark span the
-    host was in at the middle of each gap (first device only)."""
+    host was in at the middle of each gap (first device only: on a node
+    that spans several chips one SPMD program runs on all of them, which
+    step in lockstep)."""
     lo, hi = trace.window
     first = sorted(trace.devices)[0]
     _, st, en = trace.devices[first]

@@ -15,6 +15,12 @@ Everything a cell is made of is found by name: ``BENCHMARK.json`` →
 ``bench/configs/<config>.json``, ``bench/traffic/<mix>.json`` and
 ``bench/metrics/<metric>.py``; each served model's program config,
 reference and counts → ``bench/archs/<architecture>.py``.
+
+A config that spans several chips declares its mesh, axis names (the
+program's: ``data``, ``model``) to sizes in order, e.g. ``"mesh":
+{"model": 4}``.  The node is built over it, and the reference lays its
+weights over the same devices.  Without the key the node runs on one
+device, with ``mesh=None``.
 """
 from __future__ import annotations
 
@@ -67,7 +73,14 @@ class Cell:
     per_layer: List[dict]
 
 
+def mesh_size(cfg: dict) -> int:
+    """Devices the config's ``mesh`` spans: 1 without one."""
+    return math.prod(cfg.get('mesh', {}).values())
+
+
 def load_cell(name: str) -> Cell:
+    """The cell ``name`` of ``BENCHMARK.json``.  Its config's mesh, its
+    config's ``chips`` and the cell's ``chips`` have to agree."""
     bench = load_json(ROOT / 'BENCHMARK.json')
     cells = {w['name']: w for w in bench['workloads']}
     if name not in cells:
@@ -79,8 +92,26 @@ def load_cell(name: str) -> Cell:
     names = {m['name'] for m in reports}
     layer = [m for m in bench['per_layer']
              if name in m.get('workloads', [name]) and m['moves'] in names]
-    return Cell(name, int(w['chips']), load_json(ROOT / cfg_entry['file']),
-                workload.load_mix(w['traffic']), reports, layer)
+    cfg = load_json(ROOT / cfg_entry['file'])
+    chips = (mesh_size(cfg), cfg['chips'], w['chips'])
+    if len(set(chips)) != 1:
+        raise ValueError(
+            f'cell {name}: config {cfg_entry["file"]} declares a mesh of '
+            f'{chips[0]} device(s) ({cfg.get("mesh", "no mesh key")}) and '
+            f'chips {chips[1]}; BENCHMARK.json gives the cell chips '
+            f'{chips[2]}: the three have to agree')
+    return Cell(name, int(w['chips']), cfg, workload.load_mix(w['traffic']),
+                reports, layer)
+
+
+def node_mesh(cfg: dict):
+    """The program's mesh over the first devices for the config's
+    ``mesh``, or None where it declares none (a one-device mesh would
+    move the engines off their single-device path)."""
+    if 'mesh' not in cfg:
+        return None
+    from repro.launch.mesh import make_mesh
+    return make_mesh(tuple(cfg['mesh'].values()), tuple(cfg['mesh']))
 
 
 def model_config(entry: dict, page_size: int):
@@ -145,6 +176,7 @@ class Run:
     """What one run recorded; the per-layer readers take it as input."""
     cell: Cell
     seconds: float
+    chips: int = 1              # the devices the node spans
     w0: float = 0.0
     w1: float = 0.0
     online: List[e2e.OnlineRecord] = field(default_factory=list)
@@ -372,7 +404,8 @@ async def drive(node, run: Run, mix: dict, seed: int, clock: Clock, *,
             run.online.append(rec)
             streams.append(loop.create_task(_stream(app, rec, clock)))
         await marks
-        deadline = run.w1 + drain_s
+        # stopping the profiler holds the loop; the drain starts after it
+        deadline = run.w1 + drain_s + run.counters.get('trace_stop_s', 0.0)
         while clock.now() < deadline and not all(s.done() for s in streams):
             await clock.until(min(deadline, clock.now() + 0.05))
         for s in streams:
@@ -429,7 +462,9 @@ async def _marks(node, run: Run, clock: Clock, trace: bool,
         run.traced = (run.traced[0], clock.now())
         with jax.profiler.TraceAnnotation('bench.window'):
             pass
+        t = clock.now()
         jax.profiler.stop_trace()
+        run.counters['trace_stop_s'] = clock.now() - t
 
 
 def stalls(run: Run) -> str:
@@ -497,10 +532,11 @@ COMPARED = ('gap_mean',)
 
 
 def compare(run: Run, cfg: dict, mix: dict, model_seed: int, seed: int,
-            control: bool = False) -> Dict[str, dict]:
+            control: bool = False, mesh=None) -> Dict[str, dict]:
     """The readings of each model's sample of served tokens (and the
-    control's, with ``control``).  Offline models are compared where the
-    mix sends offline work."""
+    control's, with ``control``), each reference's weights laid over
+    ``mesh``.  Offline models are compared where the mix sends offline
+    work."""
     rng = np.random.default_rng([seed, 3])
     out = {}
     done = [r for r in run.online if r.ok and r.in_window]
@@ -521,7 +557,7 @@ def compare(run: Run, cfg: dict, mix: dict, model_seed: int, seed: int,
                          'control': dict.fromkeys(READINGS, math.inf),
                          'tokens': 0, 'requests': 0}
             continue
-        ref = reference.Reference(entry['config'], mseed)
+        ref = reference.Reference(entry['config'], mseed, mesh=mesh)
         gaps, ctl = [], []
         for prompt, toks in reqs:
             g, c = ref.gaps(prompt, toks, control=control)
@@ -546,13 +582,27 @@ def metric_reader(name: str) -> Callable:
     return mod.read
 
 
+def live_bytes(devices) -> List[int]:
+    """Bytes of the live jax arrays on each of ``devices``."""
+    import jax
+    held = dict.fromkeys(devices, 0)
+    for a in jax.live_arrays():
+        for sh in a.addressable_shards:
+            if sh.device in held:
+                held[sh.device] += sh.data.nbytes
+    return list(held.values())
+
+
+def peak_bytes(devices) -> List[int]:
+    """Each device's peak bytes in use, where the backend reports it."""
+    return [int((d.memory_stats() or {}).get('peak_bytes_in_use', 0))
+            for d in devices]
+
+
 def device_info(devices) -> dict:
-    peak = 0
-    for d in devices:
-        stats = d.memory_stats() or {}
-        peak = max(peak, int(stats.get('peak_bytes_in_use', 0)))
     return {'platform': devices[0].platform, 'kind': devices[0].device_kind,
-            'count': len(devices), 'memory_peak_bytes': peak}
+            'count': len(devices),
+            'memory_peak_bytes': max(peak_bytes(devices))}
 
 
 def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
@@ -578,7 +628,8 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
     jax.config.update('jax_persistent_cache_min_compile_time_secs', 0)
     cc = CompileClock()
     gcc = GcClock()
-    devices = jax.devices()
+    mesh = node_mesh(cfg)
+    devices = jax.devices()[:mesh_size(cfg)]
     peaks = None
     if devices[0].platform == 'tpu':
         table = load_json(BENCH / 'peaks.json')
@@ -591,14 +642,15 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
     t_build = time.monotonic()
     node = build_node(model_config(cfg['online'], page),
                       [model_config(o, page) for o in cfg['offline']],
-                      seed=model_seed, clock=clock, **cfg['node'])
+                      seed=model_seed, clock=clock, mesh=mesh,
+                      **cfg['node'])
     if on_node is not None:
         on_node(node)
     t_warm = time.monotonic()
     warm_up(node, seed)
     t_ramp = time.monotonic()
     hclock = Clock(node.clock)
-    run = Run(cell, float(seconds), peaks=peaks)
+    run = Run(cell, float(seconds), chips=len(devices), peaks=peaks)
     run.engines = {label: (cfg['online'] if eng.cfg.klass == 'online'
                            else cfg['offline'][i - 1])['config']
                    for i, (label, eng) in enumerate(node.names.items())}
@@ -610,6 +662,7 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
     checks = guarantees(node)
     gate = gate_state(node)
     device = device_info(devices)
+    peak_each = peak_bytes(devices)
     window_compiles = (run.counters['compiles_w1']['n']
                        - run.counters['compiles_w0']['n'])
     del node
@@ -624,6 +677,9 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
     say(f'online requests due in the window: {om["n"]} (failed '
         f'{om["failed"]}); with >= 2 tokens: {om["n_tpot"]}; in the ramp '
         f'{sum(not r.in_window for r in run.online)}')
+    for r in [r for r in run.online if r.in_window and not r.ok][:5]:
+        say(f'failed online request due at window +{r.due - run.w0:.3f} s, '
+            f'{len(r.prompt)} prompt tokens: {r.status}')
     say(f'set-up: to the build {t_build - t_start:.3f} s, build '
         f'{t_warm - t_build:.3f} s, warm-up {t_ramp - t_warm:.3f} s, ramp '
         f'{run.counters["w0_real"] - t_ramp:.3f} s')
@@ -637,12 +693,16 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
     say(f'compilations inside the window: {window_compiles}; in set-up: '
         f'{compiles_setup}; compile cache {cache_dir}')
     g0, g1 = run.counters['gc_w0'], run.counters['gc_w1']
+    say(f'memory peak per device: {peak_each}')
     say(f'garbage collection inside the window: {g1["s"] - g0["s"]:.3f} s, '
         f'{g1["full"] - g0["full"]} full collections')
     say('end to end: ' + ', '.join(f'{k} {v:.6g}'
                                    for k, v in e2e_values.items()))
     metrics = {}
     if trace:
+        say(f'profiler stop held the host '
+            f'{run.counters["trace_stop_s"]:.1f} s after the window')
+        t_read = time.monotonic()
         dt = _devtrace()
         run.trace = dt.load(dt.find_xplane(str(TRACE_DIR)))
         device['busy_s'] = dt.busy_s(run.trace)
@@ -655,14 +715,19 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
                     f'run; left out')
                 continue
             metrics[m['name']] = {'value': float(v), 'unit': m['unit']}
+        say(f'trace read in {time.monotonic() - t_read:.1f} s')
     else:
         for m in cell.end_to_end:
             metrics[m['name']] = {'value': float(e2e_values[m['name']]),
                                   'unit': m['unit']}
 
+    # the node's buffers have to be gone: a reference the size of the
+    # served model does not fit beside it
+    say(f'device bytes live as the reference starts, per device: '
+        f'{live_bytes(devices)}')
     t_ref = time.monotonic()
     result_control = {}
-    gaps = compare(run, cfg, mix, model_seed, seed, control)
+    gaps = compare(run, cfg, mix, model_seed, seed, control, mesh)
     say(f'reference compared {sum(g["tokens"] for g in gaps.values())} '
         f'served tokens of {sum(g["requests"] for g in gaps.values())} '
         f'requests in {time.monotonic() - t_ref:.1f} s')

@@ -4,7 +4,9 @@ intervals on the first device, as ``devtrace`` computes it; it is counted
 where it falls inside the program's ``engine.step.<phase>:online...``
 spans in the traced stretch, and divided by the number of online
 ``engine.step.launch`` spans there.  The host work of a step that the chip
-waits on; moves ``tpot_p90_ms``."""
+waits on; moves ``tpot_p90_ms``.  On a node that spans several chips only
+the first is read: one SPMD program runs on all of them, which step in
+lockstep."""
 import numpy as np
 
 import devtrace

@@ -2,9 +2,10 @@
 (``flops.mixed_step_flops`` for mixed steps: real prefill tokens and
 decode rows, not the padding of the fixed dispatch shape, causal attention
 over the live context; ``flops.decode_step_flops`` for pure-decode steps)
-over their summed host time times the chip's bf16 peak, in %.  A faster
-offline step is a shorter wait for an online request that arrives during
-it: moves ``ttft_p90_ms``."""
+over their summed host time times the node's bf16 peak (one chip's
+times the chips the node spans), in %.  A faster offline step is a shorter
+wait for an online request that arrives during it: moves
+``ttft_p90_ms``."""
 import flops
 
 
@@ -20,4 +21,4 @@ def read(run):
         return None
     work = sum(step_flops(run.engines[s.engine], s) for s in steps)
     t = sum(s.t1 - s.t0 for s in steps)
-    return 100.0 * work / (t * run.peaks['bf16_flops_per_s'])
+    return 100.0 * work / (t * run.peaks['bf16_flops_per_s'] * run.chips)

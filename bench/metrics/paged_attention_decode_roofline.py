@@ -6,7 +6,10 @@ engine (``flops.paged_decode_calls``, from the model's architecture), the
 larger of its operations over the bf16 peak and its bytes over the HBM
 bandwidth (q, out, and the K and V of the tokens the call attends to, not
 the pages the kernel walks).  Device time: the summed duration of the
-kernel's ops in the trace.  Moves ``tpot_p90_ms``."""
+kernel's ops in the trace, over every traced device.  On a node that spans
+several chips each chip runs its part of every call, so the whole calls'
+work against the device time of all chips reads the mean share per chip;
+on one chip it is that chip's.  Moves ``tpot_p90_ms``."""
 import sys
 from collections import Counter
 

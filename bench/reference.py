@@ -21,6 +21,7 @@ from __future__ import annotations
 import archs
 
 
-def Reference(hf: dict, seed: int):
-    """One model's reference from its published config and the seed."""
-    return archs.of(hf).Reference(hf, seed)
+def Reference(hf: dict, seed: int, mesh=None):
+    """One model's reference from its published config and the seed, with
+    its weights laid over ``mesh`` (the node's) where one is given."""
+    return archs.of(hf).Reference(hf, seed, mesh=mesh)

@@ -8,7 +8,8 @@ arrivals with the lengths of the named mix's first online stream, at each
 rate in turn (ascending) and, at each rate, once per seed.  Each
 measurement starts from an idle node: the one before it has drained
 completely (every stream finished, nothing queued or running), so no
-backlog carries from one measurement into the next.
+backlog carries from one measurement into the next.  A configuration
+that declares a mesh is served over it, as a cell's run serves it.
 
 For each measurement it prints what was offered and completed per second
 in the window, the median time to first token of the window's first and
@@ -124,7 +125,8 @@ def main(argv=None) -> int:
     node = build_node(harness.model_config(cfg['online'], page),
                       [harness.model_config(o, page)
                        for o in cfg['offline']],
-                      seed=model_seed, **cfg['node'])
+                      seed=model_seed, mesh=harness.node_mesh(cfg),
+                      **cfg['node'])
     harness.warm_up(node, model_seed)
     print(f'sweep {args.config} / {args.mix}: set-up '
           f'{time.monotonic() - T_START:.1f} s', flush=True)

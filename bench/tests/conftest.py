@@ -1,8 +1,19 @@
-"""The benchmark's CPU tests: ``python -m pytest bench/tests -q``."""
+"""The benchmark's CPU tests: ``python -m pytest bench/tests -q``.
+
+The host platform presents four virtual devices, so a cell whose config
+declares a mesh runs here over four; the flag only takes effect if it is
+set before the first ``jax`` import, hence at module scope."""
 import copy
 import os
 import sys
 from pathlib import Path
+
+N_DEVICES = 4
+_flag = f'--xla_force_host_platform_device_count={N_DEVICES}'
+if 'xla_force_host_platform_device_count' not in os.environ.get('XLA_FLAGS',
+                                                                 ''):
+    os.environ['XLA_FLAGS'] = (f"{os.environ.get('XLA_FLAGS', '')} "
+                               f"{_flag}").strip()
 
 BENCH = Path(__file__).resolve().parents[1]
 sys.path.insert(0, str(BENCH))

@@ -145,8 +145,8 @@ TOY_ARCH = textwrap.dedent('''
     class Reference(_decoder.Reference):
         mark = MARK
 
-        def __init__(self, hf, seed):
-            super().__init__(hf, seed, qk_norm=True)
+        def __init__(self, hf, seed, mesh=None):
+            super().__init__(hf, seed, mesh, qk_norm=True)
 
 
     def body_params(hf):

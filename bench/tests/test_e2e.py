@@ -77,3 +77,36 @@ def test_virtual_clock_replay(capsys):
     assert m['tpot_p90_ms'] >= 0.0
     assert res['attempted'] >= 1 and res['failed'] == 0
     assert 'compilations inside the window: 0' in err
+
+
+def test_drain_starts_after_the_profiler_stop(monkeypatch, capsys):
+    """Stopping the profiler holds the loop after the window (on four chips
+    for longer than the drain); the requests due in the window still get
+    the whole drain after it, and the run says how long the hold was."""
+    import time
+    import types
+
+    import jax
+    import numpy as np
+    from repro.core.clock import VirtualClock
+
+    import devtrace
+
+    clock = VirtualClock()
+    monkeypatch.setattr(jax.profiler, 'start_trace', lambda *a, **k: None)
+    monkeypatch.setattr(jax.profiler, 'stop_trace',
+                        lambda: clock.advance(1.5 * harness.DRAIN_S))
+    ops = (['%fusion.1 = bf16[8] fusion()'], np.asarray([0], np.int64),
+           np.asarray([10 ** 9], np.int64))
+    trace = devtrace.Trace({'/device:TPU:0': ops}, [], (0, 2 * 10 ** 9))
+    stub = types.SimpleNamespace(**{**vars(devtrace),
+                                    'find_xplane': lambda d: d,
+                                    'load': lambda path: trace})
+    monkeypatch.setattr(harness, '_devtrace', lambda: stub)
+    res = harness.run_cell('qwen_on.chat_alone', 2 ** 31 + 5, 4.0, True,
+                           t_start=time.monotonic(), adapt=shrink,
+                           clock=clock)
+    err = capsys.readouterr().err
+    assert f'profiler stop held the host {1.5 * harness.DRAIN_S:.1f} s' in err
+    assert 'failed online request' not in err
+    assert res['correct'] and res['failed'] == 0, err
